@@ -204,6 +204,8 @@ def main() -> None:
                     help="baseline rows faster than this are judged on "
                          "absolute slowdown too (timer noise)")
     args = ap.parse_args()
+    from repro.common import compile_cache
+    compile_cache.enable()
     if args.suite:
         _run_suite(args)
         return

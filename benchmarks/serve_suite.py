@@ -976,8 +976,11 @@ def run_suite(shapes: str = "serving", include_interp: bool = False,
                                          derived)
 
         # --- mesh-sharded scaling grid (subprocess, jnp) --------------
-        entries += _sharded_entries(backend, mode, grid_name, cfg,
-                                    derived)
+        # fake CPU devices only: on a chip host the child could not get
+        # the chip this process holds (common/subproc.run_subprocess)
+        if jax.default_backend() != "tpu":
+            entries += _sharded_entries(backend, mode, grid_name, cfg,
+                                        derived)
 
         if shapes == "serving" and backend == "jnp":
             # acceptance contract (jnp rows, full grid only — the tiny

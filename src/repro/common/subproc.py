@@ -26,10 +26,23 @@ _DEVICE_FLAG = "--xla_force_host_platform_device_count"
 
 def run_subprocess(code, *, devices=1, timeout=300):
     """Run ``code`` in a fresh interpreter with ``devices`` fake CPU
-    devices and return its stdout; raises AssertionError on failure."""
+    devices and return its stdout; raises AssertionError on failure.
+
+    Raises RuntimeError when this process already holds a TPU: a chip
+    belongs to one process at a time, and the fake-device child is a
+    CPU-only construct anyway (it runs with ``JAX_PLATFORMS=cpu``)."""
+    if "jax" in sys.modules:
+        from jax._src import xla_bridge
+        if (xla_bridge.backends_are_initialized()
+                and sys.modules["jax"].default_backend() == "tpu"):
+            raise RuntimeError(
+                "run_subprocess: this process holds the TPU; a child "
+                "process cannot share the chip — run the multi-device "
+                "path in-process instead")
     env = dict(os.environ)
     env["PYTHONPATH"] = SRC + os.pathsep + env.get("PYTHONPATH", "")
     env["XLA_FLAGS"] = f"{_DEVICE_FLAG}={int(devices)}"
+    env["JAX_PLATFORMS"] = "cpu"
     out = subprocess.run([sys.executable, "-c", code], env=env,
                          capture_output=True, text=True, timeout=timeout)
     if out.returncode != 0:

@@ -3,7 +3,8 @@ configs from the assignment sheet) plus the paper's own models.
 
 Each module exports:
     ARCH            — metadata dict (family, source, notes)
-    full()          — the exact published config (dry-run only)
+    full()          — the exact published config (runs on a chip where
+                      it fits: phi-1.5 on one v5e; chip_smoke.py)
     smoke()         — reduced same-family config (CPU tests)
     PEFT_TARGETS    — default ETHER target regex for this family
 """

@@ -1,5 +1,5 @@
 """smollm-360m [dense] — 32L d_model=960 15H (GQA kv=5) d_ff=2560
-vocab=49152, llama-arch small. [hf:HuggingFaceTB/SmolLM-135M; hf]
+vocab=49152, llama-arch small. [hf:HuggingFaceTB/SmolLM-360M; hf]
 long_500k SKIPPED (full attention). Also the ~100M-class end-to-end
 training example target (examples/train_smollm.py uses smoke()+).
 """

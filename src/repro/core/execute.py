@@ -90,9 +90,23 @@ def available(op: str) -> tuple[str, ...]:
 
 def supports(op: str, *args, **kwargs) -> bool:
     """True when the Pallas kernel's tiling constraints accept these
-    operand shapes."""
+    operand shapes, and the program being traced can hold the kernel."""
     rule = _SUPPORTS.get(op)
-    return bool(rule(*args, **kwargs)) if rule else False
+    if rule is None or _spmd_on_tpu():
+        return False
+    return bool(rule(*args, **kwargs))
+
+
+def _spmd_on_tpu() -> bool:
+    """True while tracing under a multi-device mesh on a TPU.  GSPMD
+    cannot partition a Mosaic kernel (it would need a shard_map), so
+    the sharded serve engine (DESIGN.md §14) runs every adapter op on
+    its jnp path there; interpret-mode kernels are plain HLO and
+    partition like any other op."""
+    from repro.parallel.context import get_context
+    ctx = get_context()
+    return (ctx is not None and ctx.mesh.size > 1
+            and jax.default_backend() == "tpu")
 
 
 def selected_backend(op: str, backend: str, *args, **kwargs) -> str:
@@ -156,17 +170,18 @@ def reset_counters() -> None:
 # `auto` selects pallas exactly when the wrapper would not itself fall
 # back to the jnp reference.
 #
-# The ETHER+/batched-GEMM tier relaxes the 128-lane constraint off-TPU:
-# interpret mode (the only Pallas execution path on CPU/GPU) has no lane
-# tiling, so `auto` can keep serving-shape smoke configs (d_model=96) on
-# the kernel path there, while real TPUs still require 128-aligned
-# feature dims.  The original rank-1 op rules are unchanged.
+# The TPU's (8, 128) block tiling is required only on a real TPU
+# (`lane_ok`): interpret mode (the only Pallas execution path on CPU/GPU)
+# has no tiling, so `auto` keeps smoke configs (d_model=64/96) on the
+# kernel path there.  tests/test_tpu_compile.py compiles the kernels for
+# a v5e at real widths, which is what keeps these rules true on a chip.
 # ---------------------------------------------------------------------------
 
-def lane_ok(dim: int) -> bool:
-    """Feature-dim lane constraint: 128-aligned on a real TPU; interpret
-    mode (off-TPU emulation) has no lane tiling."""
-    return dim % 128 == 0 or jax.default_backend() != "tpu"
+def lane_ok(dim: int, align: int = 128) -> bool:
+    """Block-dim tiling constraint: ``align``-aligned on a real TPU (128
+    lanes for a block's last dim, 8 sublanes for its second-to-last);
+    interpret mode (off-TPU emulation) has no tiling."""
+    return dim % align == 0 or jax.default_backend() != "tpu"
 
 
 def largest_divisor(n: int, cap: int) -> int:
@@ -216,16 +231,14 @@ def _sup_hh_gemm(x, w, u) -> bool:
     d, f = w.shape
     t = math.prod(x.shape[:-1]) if x.ndim > 1 else 1
     n, db = u.shape
-    bm = 128 if t % 128 == 0 else (t if 0 < t <= 256 else 0)
-    bf = 128 if f % 128 == 0 else 0
-    bk = db * max(1, min(512, d) // db)
-    return bool(bm and bf and d % bk == 0)
+    return n * db == d and all(gemm_tiles(t, d, f, db))
 
 
 @supports_rule("ether_merge")
 def _sup_merge(w, u) -> bool:
-    f = w.shape[-1]
-    return f % 512 == 0 or f % 128 == 0
+    # (db, Tf) tiles of W with the block's vector as a (db, 1) column
+    n, db = u.shape
+    return lane_ok(w.shape[-1]) and (n == 1 or lane_ok(db, 8))
 
 
 @supports_rule("ether_reflect_batched")
@@ -235,8 +248,7 @@ def _sup_reflect_batched(x, u_bank, ids) -> bool:
     _, s, d = x.shape
     _, n, db = u_bank.shape
     bs = min(128, s)
-    # lane-dim friendliness on real TPUs: the feature dim must tile.
-    return bs > 0 and s % bs == 0 and d % 128 == 0 and n * db == d
+    return bs > 0 and s % bs == 0 and lane_ok(d) and n * db == d
 
 
 @supports_rule("etherplus_gemm")
@@ -281,9 +293,9 @@ def _sup_ep_merge(w, u1, v1, u2=None, v2=None) -> bool:
     n, db = u1.shape
     if n * db != d or u1.shape != v1.shape:
         return False
-    right_ok = u2 is None or (lane_ok(u2.shape[1]) and u2.shape == v2.shape
+    right_ok = u2 is None or (u2.shape == v2.shape
                               and u2.shape[0] * u2.shape[1] == f)
-    return lane_ok(f) and right_ok
+    return lane_ok(f) and (n == 1 or lane_ok(db, 8)) and right_ok
 
 
 # ---------------------------------------------------------------------------

@@ -117,7 +117,7 @@ def _delora_batched_kernel(ids_ref, s_ref, a_ref, b_ref, x_ref, w_ref,
 
     @pl.when(k == pl.num_programs(3) - 1)
     def _done():
-        hs = h_ref[...] * s_ref[...].astype(jnp.float32)
+        hs = h_ref[...] * s_ref[0].astype(jnp.float32)
         y = acc_ref[...] + jax.lax.dot_general(
             hs, b_ref[0].astype(jnp.float32),
             (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
@@ -150,8 +150,8 @@ def delora_gemm_batched_pallas(x: jax.Array, w: jax.Array,
         num_scalar_prefetch=1,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, r),
-                         lambda i, j, jf, k, ids_ref: (ids_ref[i], 0)),
+            pl.BlockSpec((1, 1, r),
+                         lambda i, j, jf, k, ids_ref: (ids_ref[i], 0, 0)),
             pl.BlockSpec((1, block_k, r),
                          lambda i, j, jf, k, ids_ref: (ids_ref[i], k, 0)),
             pl.BlockSpec((1, r, block_f),
@@ -171,4 +171,4 @@ def delora_gemm_batched_pallas(x: jax.Array, w: jax.Array,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((bsz, seq, f), x.dtype),
         interpret=interpret,
-    )(ids.astype(jnp.int32), s_bank, a_bank, b_bank, x, w)
+    )(ids.astype(jnp.int32), s_bank.reshape(na, 1, r), a_bank, b_bank, x, w)

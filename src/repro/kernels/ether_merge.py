@@ -4,7 +4,8 @@ Used for merging adapters at deployment (zero-latency serving) and as the
 paper-faithful weight-side training mode. One grid step processes one
 (db × Tf) tile of W with its block's hyperplane vector: the rank-1 update
 ``W_i − 2û_i(û_iᵀW_i)`` — O(d·f) total, independent of n (DESIGN.md §3,
-"Identity 2").
+"Identity 2").  The block's vector rides as a (db, 1) column, so every
+tile is (8, 128)-aligned whenever db % 8 == 0 and Tf % 128 == 0.
 """
 
 from __future__ import annotations
@@ -17,12 +18,11 @@ from jax.experimental import pallas as pl
 
 
 def _merge_kernel(u_ref, w_ref, o_ref):
-    u = u_ref[...].astype(jnp.float32)                       # (1, db)
+    u = u_ref[...].astype(jnp.float32)                       # (db, 1)
     un = u / (jnp.sqrt(jnp.sum(u * u)) + 1e-8)
     w = w_ref[...].astype(jnp.float32)                       # (db, Tf)
-    proj = jax.lax.dot_general(un, w, (((1,), (0,)), ((), ())),
-                               preferred_element_type=jnp.float32)  # (1, Tf)
-    o_ref[...] = (w - 2.0 * un[0][:, None] * proj[0][None, :]).astype(o_ref.dtype)
+    proj = jnp.sum(un * w, axis=0, keepdims=True)            # ûᵀW_i: (1, Tf)
+    o_ref[...] = (w - 2.0 * un * proj).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("block_f", "interpret"))
@@ -43,10 +43,10 @@ def ether_merge_pallas(w: jax.Array, u: jax.Array, *, block_f: int = 512,
         _merge_kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, db), lambda i, j: (i, 0)),
+            pl.BlockSpec((db, 1), lambda i, j: (i, 0)),
             pl.BlockSpec((db, block_f), lambda i, j: (i, j)),
         ],
         out_specs=pl.BlockSpec((db, block_f), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((d, f), w.dtype),
         interpret=interpret,
-    )(u, w)
+    )(u.reshape(d, 1), w)

@@ -5,9 +5,11 @@ This is the hot op of the *activation-side* ETHER execution mode
 dim.  Cost O(tokens·d) — the GEMM that follows consumes the frozen weight
 unchanged, so ETHER adds zero weight-side HBM traffic.
 
-Tiling: tokens are tiled by ``block_t`` rows; the full (n, db) hyperplane
-bank rides along in VMEM (a few KB — ETHER params are tiny by design).
-VMEM per step ≈ 2·block_t·d·4B + n·db·4B; block_t=256, d=8192 → ~16 MB.
+Tiling: tokens are tiled by ``block_t`` rows; the whole hyperplane
+adapter rides along in VMEM as one flat (1, d) row (a few KB — ETHER
+params are tiny by design; kernels/blockwise.py).  VMEM per step ≈
+4·block_t·d·dtype + f32 temporaries; block_t=256, d=8192 needs the raised
+scoped limit ``blockwise.ROW_VMEM``.
 """
 
 from __future__ import annotations
@@ -18,17 +20,14 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import blockwise as bw
 
-def _reflect_kernel(u_ref, x_ref, o_ref, *, n: int, db: int):
-    u = u_ref[...].astype(jnp.float32)                       # (n, db)
-    norm = jnp.sqrt(jnp.sum(u * u, axis=-1, keepdims=True))
-    un = u / (norm + 1e-8)
-    x = x_ref[...].astype(jnp.float32)                       # (Tm, d)
-    tm = x.shape[0]
-    xb = x.reshape(tm, n, db)
-    proj = jnp.einsum("tnb,nb->tn", xb, un)                  # ûᵀx per block
-    out = xb - 2.0 * proj[..., None] * un[None]
-    o_ref[...] = out.reshape(tm, n * db).astype(o_ref.dtype)
+
+def _reflect_kernel(u_ref, x_ref, o_ref, *, db: int):
+    e = bw.block_matrix(x_ref.shape[1], db)
+    un = bw.unit(u_ref[...].astype(jnp.float32), e)          # (1, d)
+    out = bw.update(x_ref[...].astype(jnp.float32), [(un, -2.0)], e)
+    o_ref[...] = out.astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("block_t", "interpret"))
@@ -51,13 +50,14 @@ def ether_reflect_pallas(x: jax.Array, u: jax.Array, *, block_t: int = 256,
     block_t = largest_divisor(t, block_t)
     grid = (t // block_t,)
     return pl.pallas_call(
-        functools.partial(_reflect_kernel, n=n, db=db),
+        functools.partial(_reflect_kernel, db=db),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((n, db), lambda i: (0, 0)),         # whole bank
+            pl.BlockSpec((1, d), lambda i: (0, 0)),          # whole adapter
             pl.BlockSpec((block_t, d), lambda i: (i, 0)),
         ],
         out_specs=pl.BlockSpec((block_t, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((t, d), x.dtype),
+        compiler_params=bw.ROW_VMEM,
         interpret=interpret,
-    )(u, x)
+    )(u.reshape(1, d), x)

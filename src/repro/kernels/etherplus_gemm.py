@@ -34,17 +34,18 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-
-def _rank2_rows(xb, u, v):
-    """xb: (T, nb, db) f32; u, v: (nb, db) raw. x − û(ûᵀx) + v̂(v̂ᵀx)."""
-    un = u / (jnp.sqrt(jnp.sum(u * u, -1, keepdims=True)) + 1e-8)
-    vn = v / (jnp.sqrt(jnp.sum(v * v, -1, keepdims=True)) + 1e-8)
-    pu = jnp.einsum("tnb,nb->tn", xb, un)
-    pv = jnp.einsum("tnb,nb->tn", xb, vn)
-    return xb - pu[..., None] * un[None] + pv[..., None] * vn[None]
+from repro.kernels import blockwise as bw
 
 
-def _ep_body(u1_ref, v1_ref, x_ref, w_ref, acc_ref, *, nk: int, db: int):
+def _rank2(x, u, v, db):
+    """x: (T, K) f32; u, v: (1, K) raw. Blockwise x − û(ûᵀx) + v̂(v̂ᵀx)."""
+    e = bw.block_matrix(x.shape[1], db)
+    un = bw.unit(u.astype(jnp.float32), e)
+    vn = bw.unit(v.astype(jnp.float32), e)
+    return bw.update(x, [(un, -1.0), (vn, 1.0)], e)
+
+
+def _ep_body(u1_ref, v1_ref, x_ref, w_ref, acc_ref, *, db: int):
     """Shared k-step: rank-2 reflect the x-tile, accumulate the GEMM."""
     k = pl.program_id(2)
 
@@ -52,20 +53,15 @@ def _ep_body(u1_ref, v1_ref, x_ref, w_ref, acc_ref, *, nk: int, db: int):
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    x = x_ref[...].astype(jnp.float32)                       # (Tm, Tk)
-    tm, tk = x.shape
-    xr = _rank2_rows(x.reshape(tm, nk, db),
-                     u1_ref[...].astype(jnp.float32),
-                     v1_ref[...].astype(jnp.float32)).reshape(tm, tk)
-    acc_ref[...] += jax.lax.dot_general(
-        xr, w_ref[...].astype(jnp.float32),
-        (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
+    x = x_ref[...]                                           # (Tm, Tk)
+    xr = _rank2(x.astype(jnp.float32), u1_ref[...], v1_ref[...], db)
+    acc_ref[...] += bw.matmul(xr.astype(x.dtype), w_ref[...].astype(x.dtype),
+                              ((1,), (0,)))
 
 
 def _ep_gemm_kernel(u1_ref, v1_ref, x_ref, w_ref, o_ref, acc_ref, *,
-                    nk: int, db: int):
-    _ep_body(u1_ref, v1_ref, x_ref, w_ref, acc_ref, nk=nk, db=db)
+                    db: int):
+    _ep_body(u1_ref, v1_ref, x_ref, w_ref, acc_ref, db=db)
 
     @pl.when(pl.program_id(2) == pl.num_programs(2) - 1)
     def _done():
@@ -73,16 +69,12 @@ def _ep_gemm_kernel(u1_ref, v1_ref, x_ref, w_ref, o_ref, acc_ref, *,
 
 
 def _ep_gemm_kernel_2s(u1_ref, v1_ref, u2_ref, v2_ref, x_ref, w_ref, o_ref,
-                       acc_ref, *, nk: int, db: int, nf: int, db_out: int):
-    _ep_body(u1_ref, v1_ref, x_ref, w_ref, acc_ref, nk=nk, db=db)
+                       acc_ref, *, db: int, db_out: int):
+    _ep_body(u1_ref, v1_ref, x_ref, w_ref, acc_ref, db=db)
 
     @pl.when(pl.program_id(2) == pl.num_programs(2) - 1)
     def _done():
-        y = acc_ref[...]                                     # (Tm, Tf) f32
-        tm, tf = y.shape
-        y = _rank2_rows(y.reshape(tm, nf, db_out),
-                        u2_ref[...].astype(jnp.float32),
-                        v2_ref[...].astype(jnp.float32)).reshape(tm, tf)
+        y = _rank2(acc_ref[...], u2_ref[...], v2_ref[...], db_out)
         o_ref[...] = y.astype(o_ref.dtype)
 
 
@@ -120,30 +112,21 @@ def etherplus_gemm_pallas(x: jax.Array, w: jax.Array, u1: jax.Array,
     block_k = min(block_k, d)
     if block_k % db:
         block_k = db * max(1, block_k // db)
-    nk = block_k // db
     assert d % block_k == 0, "caller guarantees whole K-blocks (ops.py)"
     grid = (t // block_m, f // block_f, d // block_k)
-
+    in_spec = pl.BlockSpec((1, block_k), lambda i, j, k: (0, k))
     if u2 is None:
-        kernel = functools.partial(_ep_gemm_kernel, nk=nk, db=db)
-        adapter_specs = [
-            pl.BlockSpec((nk, db), lambda i, j, k: (k, 0)),
-            pl.BlockSpec((nk, db), lambda i, j, k: (k, 0)),
-        ]
-        adapter_args = (u1, v1)
+        kernel = functools.partial(_ep_gemm_kernel, db=db)
+        adapter_specs = [in_spec, in_spec]
+        adapter_args = (u1.reshape(1, d), v1.reshape(1, d))
     else:
         n_out, db_out = u2.shape
         assert n_out * db_out == f and u2.shape == v2.shape
-        nf = block_f // db_out
-        kernel = functools.partial(_ep_gemm_kernel_2s, nk=nk, db=db,
-                                   nf=nf, db_out=db_out)
-        adapter_specs = [
-            pl.BlockSpec((nk, db), lambda i, j, k: (k, 0)),
-            pl.BlockSpec((nk, db), lambda i, j, k: (k, 0)),
-            pl.BlockSpec((nf, db_out), lambda i, j, k: (j, 0)),
-            pl.BlockSpec((nf, db_out), lambda i, j, k: (j, 0)),
-        ]
-        adapter_args = (u1, v1, u2, v2)
+        kernel = functools.partial(_ep_gemm_kernel_2s, db=db, db_out=db_out)
+        out_spec = pl.BlockSpec((1, block_f), lambda i, j, k: (0, j))
+        adapter_specs = [in_spec, in_spec, out_spec, out_spec]
+        adapter_args = (u1.reshape(1, d), v1.reshape(1, d),
+                        u2.reshape(1, f), v2.reshape(1, f))
 
     return pl.pallas_call(
         kernel,

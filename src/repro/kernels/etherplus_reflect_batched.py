@@ -14,7 +14,8 @@ GEMM and again on the output side (with the u2/v2 banks) for two-sided
 ETHER+ serving — this is what makes ``--tenants N --method etherplus``
 real.
 
-Grid: (B, S/block_s).  VMEM per step ≈ 2·block_s·d·4B + 2·n·db·4B.
+Grid: (B, S/block_s); the banks ride flat as (A, 1, d) rows.  VMEM per
+step ≈ 4·block_s·d·dtype + f32 temporaries (``blockwise.ROW_VMEM``).
 """
 
 from __future__ import annotations
@@ -26,18 +27,15 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.etherplus_gemm import _rank2_rows
+from repro.kernels import blockwise as bw
+from repro.kernels.etherplus_gemm import _rank2
 
 
 def _ep_reflect_batched_kernel(ids_ref, u_ref, v_ref, x_ref, o_ref, *,
-                               n: int, db: int):
+                               db: int):
     del ids_ref  # consumed by the index maps, not the body
-    x = x_ref[0].astype(jnp.float32)                         # (bs, d)
-    bs = x.shape[0]
-    out = _rank2_rows(x.reshape(bs, n, db),
-                      u_ref[0].astype(jnp.float32),
-                      v_ref[0].astype(jnp.float32))
-    o_ref[0] = out.reshape(bs, n * db).astype(o_ref.dtype)
+    out = _rank2(x_ref[0].astype(jnp.float32), u_ref[0], v_ref[0], db)
+    o_ref[0] = out.astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("block_s", "interpret"))
@@ -52,7 +50,7 @@ def etherplus_reflect_batched_pallas(x: jax.Array, u_bank: jax.Array,
     own tenant's hyperplane pair."""
     from repro.core.execute import _interpret, largest_divisor
     b, s, d = x.shape
-    _, n, db = u_bank.shape
+    a, n, db = u_bank.shape
     assert n * db == d and u_bank.shape == v_bank.shape, (n, db, d)
     block_s = largest_divisor(s, block_s)   # odd decode shapes must work
     grid = (b, s // block_s)
@@ -60,16 +58,18 @@ def etherplus_reflect_batched_pallas(x: jax.Array, u_bank: jax.Array,
         num_scalar_prefetch=1,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, n, db), lambda i, j, ids_ref: (ids_ref[i], 0, 0)),
-            pl.BlockSpec((1, n, db), lambda i, j, ids_ref: (ids_ref[i], 0, 0)),
+            pl.BlockSpec((1, 1, d), lambda i, j, ids_ref: (ids_ref[i], 0, 0)),
+            pl.BlockSpec((1, 1, d), lambda i, j, ids_ref: (ids_ref[i], 0, 0)),
             pl.BlockSpec((1, block_s, d), lambda i, j, ids_ref: (i, j, 0)),
         ],
         out_specs=pl.BlockSpec((1, block_s, d),
                                lambda i, j, ids_ref: (i, j, 0)),
     )
     return pl.pallas_call(
-        functools.partial(_ep_reflect_batched_kernel, n=n, db=db),
+        functools.partial(_ep_reflect_batched_kernel, db=db),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, s, d), x.dtype),
+        compiler_params=bw.ROW_VMEM,
         interpret=_interpret(interpret),
-    )(ids.astype(jnp.int32), u_bank, v_bank, x)
+    )(ids.astype(jnp.int32), u_bank.reshape(a, 1, d),
+      v_bank.reshape(a, 1, d), x)

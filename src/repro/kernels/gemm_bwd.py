@@ -11,13 +11,15 @@ Backward under cotangent G, with dXr = G @ Wᵀ:
     dW = R(x)ᵀ @ G                   (frozen-weight cotangent)
 
 Two fused passes instead of one: dx+du share the dXr GEMM so they live
-in one kernel (grid (M/Tm, D/Td, F/Tf), F innermost accumulating dXr in
+in one kernel (grid (D/Td, M/Tm, F/Tf), F innermost accumulating dXr in
 f32 scratch; the reflection backward runs on the finished dXr tile and
-dL/dû accumulates in a persistent (n, db) scratch across the whole
-grid).  dW is a *separate* pallas_call so XLA can dead-code it when the
-base weight is frozen — the common PEFT case pays nothing for it.
+dL/dû for the D-tile accumulates in a (1, Td) scratch over all row
+tiles, so the du output block is finished before the D-tile moves on).
+dW is a *separate* pallas_call so XLA can dead-code it when the base
+weight is frozen — the common PEFT case pays nothing for it.
 Constraint: Td holds whole reflection blocks (Td % db == 0), mirroring
-the forward's Tk rule; ops.py enforces/falls back.
+the forward's Tk rule; ops.py enforces/falls back.  Adapters ride flat
+as (1, Td) slices (kernels/blockwise.py).
 
 The batched bank variants add a leading (B,) grid axis with
 scalar-prefetch tenant-id gathers (see householder_gemm_batched) and
@@ -36,81 +38,77 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.reflect_bwd import norm_chain, reflect_bwd_tile, unit_rows
+from repro.kernels import blockwise as bw
 
 
-def _slice_rows(ref, k, nk):
-    """Rows [k*nk, (k+1)*nk) of a resident (n, db) adapter ref (f32)."""
-    return ref[pl.dslice(k * nk, nk), :].astype(jnp.float32)
+def _dirs(rows, coeffs, e):
+    """Unit (1, Td) rows for every (raw adapter row, coeff) direction."""
+    return [(bw.unit(r.astype(jnp.float32), e), c)
+            for r, c in zip(rows, coeffs)]
 
 
-def _dx_tile(xb, dxrb, dirs):
-    """Apply the reflect backward for every (un, coeff) direction.
-
-    Returns (dx tile (T, nk, db), [ĝ per direction])."""
-    dx = dxrb
-    ghats = []
-    for un, coeff in dirs:
-        term, ghat = reflect_bwd_tile(xb, dxrb, un, coeff)
-        dx = dx + term
-        ghats.append(ghat)
-    return dx, ghats
+def _coeffs(v):
+    """Rank-1 Householder (coeff −2) or ETHER+ rank-2 (−1 / +1)."""
+    return (-2.0,) if v is None else (-1.0, 1.0)
 
 
-def _gemm_dx_kernel(u_ref, x_ref, w_ref, g_ref, dx_ref, du_ref,
-                    acc_ref, du_acc_ref, *, nk: int, db: int,
-                    rank2: bool, v_ref=None, dv_ref=None, dv_acc_ref=None):
-    i, k, f = pl.program_id(0), pl.program_id(1), pl.program_id(2)
-    nf = pl.num_programs(2)
+def _block_d(d: int, db: int, block_d: int) -> int:
+    block_d = min(block_d, d)
+    if block_d % db:
+        block_d = db * max(1, block_d // db)
+    assert d % block_d == 0, "caller guarantees whole K-blocks (ops.py)"
+    return block_d
+
+
+def _gemm_dx_kernel(*refs, db: int, coeffs, batched: bool):
+    """refs = ([ids,] *adapter rows, x, w, g, dx, *du outs, acc,
+    *du accs).  Grid (D/Td, M/Tm, F/Tf) — batched: (B, D/Td, S/Ts, F/Tf).
+    The single-tenant du outs are finished dL/du; the batched ones are
+    the sequence's un-normalized dL/dû (the wrapper scatter-adds them
+    into the bank and applies the chain rule per bank row)."""
+    m = len(coeffs)
+    refs = refs[1:] if batched else refs
+    u_refs, (x_ref, w_ref, g_ref, dx_ref) = refs[:m], refs[m:m + 4]
+    du_refs, acc_ref = refs[m + 4:2 * m + 4], refs[2 * m + 4]
+    du_accs = refs[2 * m + 5:]
+    ax = 1 if batched else 0
+    i, f = pl.program_id(ax + 1), pl.program_id(ax + 2)
+    nf = pl.num_programs(ax + 2)
+    lead = (lambda r: r[0]) if batched else (lambda r: r[...])
 
     @pl.when(f == 0)
     def _init_acc():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    @pl.when((i == 0) & (k == 0) & (f == 0))
+    @pl.when((i == 0) & (f == 0))
     def _init_du():
-        du_acc_ref[...] = jnp.zeros_like(du_acc_ref)
-        if rank2:
-            dv_acc_ref[...] = jnp.zeros_like(dv_acc_ref)
+        for acc in du_accs:
+            acc[...] = jnp.zeros_like(acc)
 
     # dXr tile accumulation: G (Tm, Tf) · Wᵀ (Tf, Td)
-    acc_ref[...] += jax.lax.dot_general(
-        g_ref[...].astype(jnp.float32), w_ref[...].astype(jnp.float32),
-        (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+    acc_ref[...] += bw.matmul(lead(g_ref), w_ref[...], ((1,), (1,)))
 
     @pl.when(f == nf - 1)
     def _finish_tile():
-        un = unit_rows(_slice_rows(u_ref, k, nk))
-        dirs = [(un, -1.0 if rank2 else -2.0)]
-        if rank2:
-            dirs.append((unit_rows(_slice_rows(v_ref, k, nk)), +1.0))
-        tm, td = acc_ref.shape
-        dxrb = acc_ref[...].reshape(tm, nk, db)
-        xb = x_ref[...].astype(jnp.float32).reshape(tm, nk, db)
-        dx, ghats = _dx_tile(xb, dxrb, dirs)
-        dx_ref[...] = dx.reshape(tm, td).astype(dx_ref.dtype)
-        du_acc_ref[pl.dslice(k * nk, nk), :] += ghats[0]
-        if rank2:
-            dv_acc_ref[pl.dslice(k * nk, nk), :] += ghats[1]
+        e = bw.block_matrix(acc_ref.shape[1], db)
+        dirs = _dirs([lead(r) for r in u_refs], coeffs, e)
+        dx, ghats = bw.update_bwd(lead(x_ref).astype(jnp.float32),
+                                  acc_ref[...], dirs, e)
+        if batched:
+            dx_ref[0] = dx.astype(dx_ref.dtype)
+        else:
+            dx_ref[...] = dx.astype(dx_ref.dtype)
+        for acc, ghat in zip(du_accs, ghats):
+            acc[...] += ghat
 
-    last = ((i == pl.num_programs(0) - 1) & (k == pl.num_programs(1) - 1)
-            & (f == nf - 1))
-
-    @pl.when(last)
-    def _emit_du():
-        u = u_ref[...].astype(jnp.float32)
-        du_ref[...] = norm_chain(u, du_acc_ref[...]).astype(du_ref.dtype)
-        if rank2:
-            v = v_ref[...].astype(jnp.float32)
-            dv_ref[...] = norm_chain(v, dv_acc_ref[...]).astype(dv_ref.dtype)
-
-
-def _rank2_kernel_shim(u_ref, v_ref, x_ref, w_ref, g_ref, dx_ref, du_ref,
-                       dv_ref, acc_ref, du_acc_ref, dv_acc_ref, *,
-                       nk: int, db: int):
-    _gemm_dx_kernel(u_ref, x_ref, w_ref, g_ref, dx_ref, du_ref, acc_ref,
-                    du_acc_ref, nk=nk, db=db, rank2=True, v_ref=v_ref,
-                    dv_ref=dv_ref, dv_acc_ref=dv_acc_ref)
+        @pl.when(i == pl.num_programs(ax + 1) - 1)
+        def _emit_du():
+            for u_ref, out, acc in zip(u_refs, du_refs, du_accs):
+                if batched:
+                    out[0] = acc[...]
+                else:
+                    u = u_ref[...].astype(jnp.float32)
+                    out[...] = bw.norm_chain(u, acc[...], e).astype(out.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("block_m", "block_d",
@@ -125,86 +123,66 @@ def reflect_gemm_dx_pallas(x: jax.Array, w: jax.Array, u: jax.Array,
     x: (T, d); w: (d, f); u[/v]: (n, db); g: (T, f).  Rank-1 Householder
     when v is None (coeff −2), ETHER+ rank-2 otherwise (−1/+1)."""
     from repro.core.execute import _interpret, largest_divisor
-    interpret = _interpret(interpret)
     t, d = x.shape
     d2, f = w.shape
     n, db = u.shape
     assert d == d2 and n * db == d and g.shape == (t, f)
     block_m = largest_divisor(t, block_m)
     block_f = largest_divisor(f, block_f)
-    block_d = min(block_d, d)
-    if block_d % db:
-        block_d = db * max(1, block_d // db)
-    nk = block_d // db
-    assert d % block_d == 0, "caller guarantees whole K-blocks (ops.py)"
-    grid = (t // block_m, d // block_d, f // block_f)
-    adapter_spec = pl.BlockSpec((n, db), lambda i, k, f: (0, 0))
-    data_specs = [
-        pl.BlockSpec((block_m, block_d), lambda i, k, f: (i, k)),   # x
-        pl.BlockSpec((block_d, block_f), lambda i, k, f: (k, f)),   # w
-        pl.BlockSpec((block_m, block_f), lambda i, k, f: (i, f)),   # g
-    ]
-    dx_spec = pl.BlockSpec((block_m, block_d), lambda i, k, f: (i, k))
-    scratch = [pltpu.VMEM((block_m, block_d), jnp.float32),
-               pltpu.VMEM((n, db), jnp.float32)]
-    if v is None:
-        return pl.pallas_call(
-            functools.partial(_gemm_dx_kernel, nk=nk, db=db, rank2=False),
-            grid=grid,
-            in_specs=[adapter_spec] + data_specs,
-            out_specs=[dx_spec, adapter_spec],
-            out_shape=[jax.ShapeDtypeStruct((t, d), x.dtype),
-                       jax.ShapeDtypeStruct((n, db), u.dtype)],
-            scratch_shapes=scratch,
-            interpret=interpret,
-        )(u, x, w, g)
-    return pl.pallas_call(
-        functools.partial(_rank2_kernel_shim, nk=nk, db=db),
-        grid=grid,
-        in_specs=[adapter_spec, adapter_spec] + data_specs,
-        out_specs=[dx_spec, adapter_spec, adapter_spec],
-        out_shape=[jax.ShapeDtypeStruct((t, d), x.dtype),
-                   jax.ShapeDtypeStruct((n, db), u.dtype),
-                   jax.ShapeDtypeStruct((n, db), v.dtype)],
-        scratch_shapes=scratch + [pltpu.VMEM((n, db), jnp.float32)],
-        interpret=interpret,
-    )(u, v, x, w, g)
+    block_d = _block_d(d, db, block_d)
+    adapters = (u,) if v is None else (u, v)
+    m = len(adapters)
+    row = pl.BlockSpec((1, block_d), lambda k, i, f: (0, k))
+    outs = pl.pallas_call(
+        functools.partial(_gemm_dx_kernel, db=db, coeffs=_coeffs(v),
+                          batched=False),
+        grid=(d // block_d, t // block_m, f // block_f),
+        in_specs=[row] * m + [
+            pl.BlockSpec((block_m, block_d), lambda k, i, f: (i, k)),   # x
+            pl.BlockSpec((block_d, block_f), lambda k, i, f: (k, f)),   # w
+            pl.BlockSpec((block_m, block_f), lambda k, i, f: (i, f)),   # g
+        ],
+        out_specs=[pl.BlockSpec((block_m, block_d), lambda k, i, f: (i, k))]
+        + [row] * m,
+        out_shape=[jax.ShapeDtypeStruct((t, d), x.dtype)]
+        + [jax.ShapeDtypeStruct((1, d), a.dtype) for a in adapters],
+        scratch_shapes=[pltpu.VMEM((block_m, block_d), jnp.float32)]
+        + [pltpu.VMEM((1, block_d), jnp.float32)] * m,
+        interpret=_interpret(interpret),
+    )(*(a.reshape(1, d) for a in adapters), x, w, g)
+    return (outs[0],) + tuple(o.reshape(n, db) for o in outs[1:])
 
 
 # ---------------------------------------------------------------------------
 # dW = R(x)ᵀ @ G — separate pass so frozen-weight training DCEs it
 # ---------------------------------------------------------------------------
 
-def _gemm_dw_kernel(u_ref, x_ref, g_ref, dw_ref, acc_ref, *, nk: int,
-                    db: int, rank2: bool, v_ref=None):
-    k, t = pl.program_id(0), pl.program_id(2)
+def _gemm_dw_kernel(*refs, db: int, coeffs, batched: bool):
+    """refs = ([ids,] *adapter rows, x, g, dw, acc).  Grid (D/Td, F/Tf,
+    M/Tm) — batched: (D/Td, F/Tf, B, S/Ts); the trailing axes reduce."""
+    m = len(coeffs)
+    refs = refs[1:] if batched else refs
+    u_refs, (x_ref, g_ref, dw_ref, acc_ref) = refs[:m], refs[m:]
+    first = pl.program_id(2) == 0
+    last = pl.program_id(2) == pl.num_programs(2) - 1
+    if batched:
+        first &= pl.program_id(3) == 0
+        last &= pl.program_id(3) == pl.num_programs(3) - 1
+    lead = (lambda r: r[0]) if batched else (lambda r: r[...])
 
-    @pl.when(t == 0)
+    @pl.when(first)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    un = unit_rows(_slice_rows(u_ref, k, nk))
-    x = x_ref[...].astype(jnp.float32)
-    tm, td = x.shape
-    xb = x.reshape(tm, nk, db)
-    cu = -1.0 if rank2 else -2.0
-    xr = xb + cu * jnp.einsum("tnb,nb->tn", xb, un)[..., None] * un[None]
-    if rank2:
-        vn = unit_rows(_slice_rows(v_ref, k, nk))
-        xr = xr + jnp.einsum("tnb,nb->tn", xb, vn)[..., None] * vn[None]
-    acc_ref[...] += jax.lax.dot_general(
-        xr.reshape(tm, td), g_ref[...].astype(jnp.float32),
-        (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+    x = lead(x_ref)
+    e = bw.block_matrix(x.shape[1], db)
+    xr = bw.update(x.astype(jnp.float32),
+                   _dirs([lead(r) for r in u_refs], coeffs, e), e)
+    acc_ref[...] += bw.matmul(xr.astype(x.dtype), lead(g_ref), ((0,), (0,)))
 
-    @pl.when(t == pl.num_programs(2) - 1)
+    @pl.when(last)
     def _done():
         dw_ref[...] = acc_ref[...].astype(dw_ref.dtype)
-
-
-def _dw_rank2_shim(u_ref, v_ref, x_ref, g_ref, dw_ref, acc_ref, *,
-                   nk: int, db: int):
-    _gemm_dw_kernel(u_ref, x_ref, g_ref, dw_ref, acc_ref, nk=nk, db=db,
-                    rank2=True, v_ref=v_ref)
 
 
 @functools.partial(jax.jit, static_argnames=("block_m", "block_d",
@@ -217,86 +195,33 @@ def reflect_gemm_dw_pallas(x: jax.Array, u: jax.Array, g: jax.Array,
                            interpret: bool | None = None) -> jax.Array:
     """dw = R(x)ᵀ @ g.  x: (T, d); g: (T, f); u[/v]: (n, db)."""
     from repro.core.execute import _interpret, largest_divisor
-    interpret = _interpret(interpret)
     t, d = x.shape
     t2, f = g.shape
     n, db = u.shape
     assert t == t2 and n * db == d
     block_m = largest_divisor(t, block_m)
     block_f = largest_divisor(f, block_f)
-    block_d = min(block_d, d)
-    if block_d % db:
-        block_d = db * max(1, block_d // db)
-    nk = block_d // db
-    assert d % block_d == 0, "caller guarantees whole K-blocks (ops.py)"
-    grid = (d // block_d, f // block_f, t // block_m)
-    adapter_spec = pl.BlockSpec((n, db), lambda k, j, t: (0, 0))
-    data_specs = [
-        pl.BlockSpec((block_m, block_d), lambda k, j, t: (t, k)),   # x
-        pl.BlockSpec((block_m, block_f), lambda k, j, t: (t, j)),   # g
-    ]
-    out_dtype = w_dtype if w_dtype is not None else x.dtype
-    if v is None:
-        kernel = functools.partial(_gemm_dw_kernel, nk=nk, db=db,
-                                   rank2=False)
-        specs, args = [adapter_spec], (u, x, g)
-    else:
-        kernel = functools.partial(_dw_rank2_shim, nk=nk, db=db)
-        specs, args = [adapter_spec, adapter_spec], (u, v, x, g)
+    block_d = _block_d(d, db, block_d)
+    adapters = (u,) if v is None else (u, v)
+    row = pl.BlockSpec((1, block_d), lambda k, j, t: (0, k))
     return pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=specs + data_specs,
+        functools.partial(_gemm_dw_kernel, db=db, coeffs=_coeffs(v),
+                          batched=False),
+        grid=(d // block_d, f // block_f, t // block_m),
+        in_specs=[row] * len(adapters) + [
+            pl.BlockSpec((block_m, block_d), lambda k, j, t: (t, k)),   # x
+            pl.BlockSpec((block_m, block_f), lambda k, j, t: (t, j)),   # g
+        ],
         out_specs=pl.BlockSpec((block_d, block_f), lambda k, j, t: (k, j)),
-        out_shape=jax.ShapeDtypeStruct((d, f), out_dtype),
+        out_shape=jax.ShapeDtypeStruct((d, f), w_dtype or x.dtype),
         scratch_shapes=[pltpu.VMEM((block_d, block_f), jnp.float32)],
-        interpret=interpret,
-    )(*args)
+        interpret=_interpret(interpret),
+    )(*(a.reshape(1, d) for a in adapters), x, g)
 
 
 # ---------------------------------------------------------------------------
 # Batched bank variants (multi-tenant training)
 # ---------------------------------------------------------------------------
-
-def _gemm_dx_batched_kernel(ids_ref, u_ref, x_ref, w_ref, g_ref, dx_ref,
-                            gu_ref, acc_ref, gu_acc_ref, *, nk: int,
-                            db: int):
-    del ids_ref  # consumed by the index maps
-    j, k, f = pl.program_id(1), pl.program_id(2), pl.program_id(3)
-    nf = pl.num_programs(3)
-
-    @pl.when(f == 0)
-    def _init_acc():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    @pl.when((j == 0) & (k == 0) & (f == 0))
-    def _init_gu():
-        gu_acc_ref[...] = jnp.zeros_like(gu_acc_ref)
-
-    acc_ref[...] += jax.lax.dot_general(
-        g_ref[0].astype(jnp.float32), w_ref[...].astype(jnp.float32),
-        (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
-
-    @pl.when(f == nf - 1)
-    def _finish_tile():
-        un = unit_rows(u_ref[0, pl.dslice(k * nk, nk), :]
-                       .astype(jnp.float32))
-        ts, td = acc_ref.shape
-        dxrb = acc_ref[...].reshape(ts, nk, db)
-        xb = x_ref[0].astype(jnp.float32).reshape(ts, nk, db)
-        dx, (ghat,) = _dx_tile(xb, dxrb, [(un, -2.0)])
-        dx_ref[0] = dx.reshape(ts, td).astype(dx_ref.dtype)
-        gu_acc_ref[pl.dslice(k * nk, nk), :] += ghat
-
-    last = ((j == pl.num_programs(1) - 1) & (k == pl.num_programs(2) - 1)
-            & (f == nf - 1))
-
-    @pl.when(last)
-    def _emit_gu():
-        # un-normalized dL/dû for THIS sequence; the wrapper scatter-adds
-        # into the bank and applies the chain rule per bank row.
-        gu_ref[0] = gu_acc_ref[...]
-
 
 @functools.partial(jax.jit, static_argnames=("block_s", "block_d",
                                              "block_f", "interpret"))
@@ -312,69 +237,42 @@ def householder_gemm_batched_bwd_pallas(x: jax.Array, w: jax.Array,
     from repro.core.execute import _interpret, largest_divisor
     b, s, d = x.shape
     d2, f = w.shape
-    _, n, db = u_bank.shape
+    a, n, db = u_bank.shape
     assert d == d2 and n * db == d and g.shape == (b, s, f)
     block_s = largest_divisor(s, block_s)
     block_f = largest_divisor(f, block_f)
-    block_d = min(block_d, d)
-    if block_d % db:
-        block_d = db * max(1, block_d // db)
-    nk = block_d // db
-    assert d % block_d == 0, "caller guarantees whole K-blocks (ops.py)"
-    grid = (b, s // block_s, d // block_d, f // block_f)
+    block_d = _block_d(d, db, block_d)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=grid,
+        grid=(b, d // block_d, s // block_s, f // block_f),
         in_specs=[
-            pl.BlockSpec((1, n, db),
-                         lambda i, j, k, f, ids_ref: (ids_ref[i], 0, 0)),
+            pl.BlockSpec((1, 1, block_d),
+                         lambda i, k, j, f, ids_ref: (ids_ref[i], 0, k)),
             pl.BlockSpec((1, block_s, block_d),
-                         lambda i, j, k, f, ids_ref: (i, j, k)),
+                         lambda i, k, j, f, ids_ref: (i, j, k)),
             pl.BlockSpec((block_d, block_f),
-                         lambda i, j, k, f, ids_ref: (k, f)),
+                         lambda i, k, j, f, ids_ref: (k, f)),
             pl.BlockSpec((1, block_s, block_f),
-                         lambda i, j, k, f, ids_ref: (i, j, f)),
+                         lambda i, k, j, f, ids_ref: (i, j, f)),
         ],
         out_specs=[
             pl.BlockSpec((1, block_s, block_d),
-                         lambda i, j, k, f, ids_ref: (i, j, k)),
-            pl.BlockSpec((1, n, db),
-                         lambda i, j, k, f, ids_ref: (i, 0, 0)),
+                         lambda i, k, j, f, ids_ref: (i, j, k)),
+            pl.BlockSpec((1, 1, block_d),
+                         lambda i, k, j, f, ids_ref: (i, 0, k)),
         ],
         scratch_shapes=[pltpu.VMEM((block_s, block_d), jnp.float32),
-                        pltpu.VMEM((n, db), jnp.float32)],
+                        pltpu.VMEM((1, block_d), jnp.float32)],
     )
-    return pl.pallas_call(
-        functools.partial(_gemm_dx_batched_kernel, nk=nk, db=db),
+    dx, ghat = pl.pallas_call(
+        functools.partial(_gemm_dx_kernel, db=db, coeffs=(-2.0,),
+                          batched=True),
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct((b, s, d), x.dtype),
-                   jax.ShapeDtypeStruct((b, n, db), jnp.float32)],
+                   jax.ShapeDtypeStruct((b, 1, d), jnp.float32)],
         interpret=_interpret(interpret),
-    )(ids.astype(jnp.int32), u_bank, x, w, g)
-
-
-def _gemm_dw_batched_kernel(ids_ref, u_ref, x_ref, g_ref, dw_ref, acc_ref,
-                            *, nk: int, db: int):
-    del ids_ref
-    k = pl.program_id(0)
-    i, j = pl.program_id(2), pl.program_id(3)
-
-    @pl.when((i == 0) & (j == 0))
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    un = unit_rows(u_ref[0, pl.dslice(k * nk, nk), :].astype(jnp.float32))
-    x = x_ref[0].astype(jnp.float32)
-    ts, td = x.shape
-    xb = x.reshape(ts, nk, db)
-    xr = xb - 2.0 * jnp.einsum("tnb,nb->tn", xb, un)[..., None] * un[None]
-    acc_ref[...] += jax.lax.dot_general(
-        xr.reshape(ts, td), g_ref[0].astype(jnp.float32),
-        (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-
-    @pl.when((i == pl.num_programs(2) - 1) & (j == pl.num_programs(3) - 1))
-    def _done():
-        dw_ref[...] = acc_ref[...].astype(dw_ref.dtype)
+    )(ids.astype(jnp.int32), u_bank.reshape(a, 1, d), x, w, g)
+    return dx, ghat.reshape(b, n, db)
 
 
 @functools.partial(jax.jit, static_argnames=("block_s", "block_d",
@@ -390,23 +288,18 @@ def householder_gemm_batched_dw_pallas(x: jax.Array, u_bank: jax.Array,
     """dw = Σ_b R_b(x_b)ᵀ @ g_b (shared frozen weight, per-tenant R)."""
     from repro.core.execute import _interpret, largest_divisor
     b, s, d = x.shape
-    _, n, db = u_bank.shape
+    a, n, db = u_bank.shape
     f = g.shape[-1]
     assert n * db == d and g.shape[:2] == (b, s)
     block_s = largest_divisor(s, block_s)
     block_f = largest_divisor(f, block_f)
-    block_d = min(block_d, d)
-    if block_d % db:
-        block_d = db * max(1, block_d // db)
-    nk = block_d // db
-    assert d % block_d == 0, "caller guarantees whole K-blocks (ops.py)"
-    grid = (d // block_d, f // block_f, b, s // block_s)
+    block_d = _block_d(d, db, block_d)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=grid,
+        grid=(d // block_d, f // block_f, b, s // block_s),
         in_specs=[
-            pl.BlockSpec((1, n, db),
-                         lambda k, jf, i, j, ids_ref: (ids_ref[i], 0, 0)),
+            pl.BlockSpec((1, 1, block_d),
+                         lambda k, jf, i, j, ids_ref: (ids_ref[i], 0, k)),
             pl.BlockSpec((1, block_s, block_d),
                          lambda k, jf, i, j, ids_ref: (i, j, k)),
             pl.BlockSpec((1, block_s, block_f),
@@ -416,10 +309,10 @@ def householder_gemm_batched_dw_pallas(x: jax.Array, u_bank: jax.Array,
                                lambda k, jf, i, j, ids_ref: (k, jf)),
         scratch_shapes=[pltpu.VMEM((block_d, block_f), jnp.float32)],
     )
-    out_dtype = w_dtype if w_dtype is not None else x.dtype
     return pl.pallas_call(
-        functools.partial(_gemm_dw_batched_kernel, nk=nk, db=db),
+        functools.partial(_gemm_dw_kernel, db=db, coeffs=(-2.0,),
+                          batched=True),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((d, f), out_dtype),
+        out_shape=jax.ShapeDtypeStruct((d, f), w_dtype or x.dtype),
         interpret=_interpret(interpret),
-    )(ids.astype(jnp.int32), u_bank, x, g)
+    )(ids.astype(jnp.int32), u_bank.reshape(a, 1, d), x, g)

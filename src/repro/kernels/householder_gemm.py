@@ -9,6 +9,7 @@ so transformed weights never exist anywhere — not in HBM, not in VMEM.
 Grid: (M/Tm, F/Tf, K/Tk), K innermost for f32 scratch accumulation.
 Constraint: Tk % db == 0 (each K-tile holds whole reflection blocks, so
 the blockwise projection is tile-local). ops.py enforces/falls back.
+The adapter rides flat as a (1, Tk) row (kernels/blockwise.py).
 """
 
 from __future__ import annotations
@@ -20,25 +21,22 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import blockwise as bw
 
-def _hh_gemm_kernel(u_ref, x_ref, w_ref, o_ref, acc_ref, *, nk: int, db: int):
+
+def _hh_gemm_kernel(u_ref, x_ref, w_ref, o_ref, acc_ref, *, db: int):
     k = pl.program_id(2)
 
     @pl.when(k == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    u = u_ref[...].astype(jnp.float32)                       # (nk, db)
-    un = u / (jnp.sqrt(jnp.sum(u * u, -1, keepdims=True)) + 1e-8)
-    x = x_ref[...].astype(jnp.float32)                       # (Tm, Tk)
-    tm, tk = x.shape
-    xb = x.reshape(tm, nk, db)
-    proj = jnp.einsum("tnb,nb->tn", xb, un)
-    xr = (xb - 2.0 * proj[..., None] * un[None]).reshape(tm, tk)
-    acc_ref[...] += jax.lax.dot_general(
-        xr, w_ref[...].astype(jnp.float32),
-        (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
+    x = x_ref[...]                                           # (Tm, Tk)
+    e = bw.block_matrix(x.shape[1], db)
+    un = bw.unit(u_ref[...].astype(jnp.float32), e)          # (1, Tk)
+    xr = bw.update(x.astype(jnp.float32), [(un, -2.0)], e)
+    acc_ref[...] += bw.matmul(xr.astype(x.dtype), w_ref[...].astype(x.dtype),
+                              ((1,), (0,)))
 
     @pl.when(k == pl.num_programs(2) - 1)
     def _done():
@@ -67,14 +65,13 @@ def householder_gemm_pallas(x: jax.Array, w: jax.Array, u: jax.Array, *,
     # whole blocks per K-tile
     if block_k % db:
         block_k = db * max(1, block_k // db)
-    nk = block_k // db
     assert t % block_m == 0 and f % block_f == 0 and d % block_k == 0
     grid = (t // block_m, f // block_f, d // block_k)
     return pl.pallas_call(
-        functools.partial(_hh_gemm_kernel, nk=nk, db=db),
+        functools.partial(_hh_gemm_kernel, db=db),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((nk, db), lambda i, j, k: (k, 0)),
+            pl.BlockSpec((1, block_k), lambda i, j, k: (0, k)),
             pl.BlockSpec((block_m, block_k), lambda i, j, k: (i, k)),
             pl.BlockSpec((block_k, block_f), lambda i, j, k: (k, j)),
         ],
@@ -82,4 +79,4 @@ def householder_gemm_pallas(x: jax.Array, w: jax.Array, u: jax.Array, *,
         out_shape=jax.ShapeDtypeStruct((t, f), x.dtype),
         scratch_shapes=[pltpu.VMEM((block_m, block_f), jnp.float32)],
         interpret=interpret,
-    )(u, x, w)
+    )(u.reshape(1, d), x, w)

@@ -89,7 +89,7 @@ def _ha_batched_kernel(ids_ref, r_ref, c_ref, x_ref, w_ref, o_ref,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     x = x_ref[0].astype(jnp.float32)                         # (Ts, Tk)
-    xr = x * r_ref[...].astype(jnp.float32)
+    xr = x * r_ref[0].astype(jnp.float32)
     acc_ref[...] += jax.lax.dot_general(
         xr, w_ref[...].astype(jnp.float32),
         (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
@@ -97,7 +97,7 @@ def _ha_batched_kernel(ids_ref, r_ref, c_ref, x_ref, w_ref, o_ref,
     @pl.when(k == pl.num_programs(3) - 1)
     def _done():
         o_ref[0] = (acc_ref[...]
-                    * c_ref[...].astype(jnp.float32)).astype(o_ref.dtype)
+                    * c_ref[0].astype(jnp.float32)).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("block_s", "block_f",
@@ -125,10 +125,10 @@ def hyperadapt_gemm_batched_pallas(x: jax.Array, w: jax.Array,
         num_scalar_prefetch=1,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, block_k),
-                         lambda i, j, jf, k, ids_ref: (ids_ref[i], k)),
-            pl.BlockSpec((1, block_f),
-                         lambda i, j, jf, k, ids_ref: (ids_ref[i], jf)),
+            pl.BlockSpec((1, 1, block_k),
+                         lambda i, j, jf, k, ids_ref: (ids_ref[i], 0, k)),
+            pl.BlockSpec((1, 1, block_f),
+                         lambda i, j, jf, k, ids_ref: (ids_ref[i], 0, jf)),
             pl.BlockSpec((1, block_s, block_k),
                          lambda i, j, jf, k, ids_ref: (i, j, k)),
             pl.BlockSpec((block_k, block_f),
@@ -143,4 +143,5 @@ def hyperadapt_gemm_batched_pallas(x: jax.Array, w: jax.Array,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((bsz, seq, f), x.dtype),
         interpret=interpret,
-    )(ids.astype(jnp.int32), r_bank, c_bank, x, w)
+    )(ids.astype(jnp.int32), r_bank.reshape(na, 1, d),
+      c_bank.reshape(na, 1, f), x, w)

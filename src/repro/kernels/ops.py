@@ -77,18 +77,16 @@ def ether_reflect_batched(x: jax.Array, u_bank: jax.Array, ids: jax.Array,
 def householder_gemm(x: jax.Array, w: jax.Array, u: jax.Array, *,
                      interpret: bool | None = None) -> jax.Array:
     """reflect(x) @ w; x: (..., d); w: (d, f)."""
+    import math
+    from repro.core import execute
     d, f = w.shape
     lead = x.shape[:-1]
-    t = 1
-    for sdim in lead:
-        t *= int(sdim)
+    t = math.prod(lead) if lead else 1
     x2 = x.reshape(t, d)
     n, db = u.shape
-    bm = 128 if t % 128 == 0 else (t if t <= 256 else 0)
-    bf = 128 if f % 128 == 0 else 0
-    bk = db * max(1, min(512, d) // db)
-    if not bm or not bf or d % bk:
+    if not execute.supports("householder_gemm", x, w, u):
         return ref.ref_householder_gemm(x2, w, u).reshape(*lead, f)
+    bm, bf, bk = gemm_tiles(t, d, f, db)
     out = householder_gemm_pallas(x2, w, u, block_m=bm, block_f=bf,
                                   block_k=bk,
                                   interpret=_interpret(interpret))
@@ -170,12 +168,17 @@ def etherplus_merge(w: jax.Array, u1: jax.Array, v1: jax.Array,
 def ether_merge(w: jax.Array, u: jax.Array, *,
                 interpret: bool | None = None) -> jax.Array:
     """H_B w for adapter absorption. w: (d, f)."""
-    d, f = w.shape
-    bf = 512 if f % 512 == 0 else (128 if f % 128 == 0 else 0)
-    if not bf:
+    from repro.core import execute
+    if not execute.supports("ether_merge", w, u):
         return ref.ref_ether_merge(w, u)
-    return ether_merge_pallas(w, u, block_f=bf,
+    return ether_merge_pallas(w, u, block_f=_merge_block_f(w.shape[1]),
                               interpret=_interpret(interpret))
+
+
+def _merge_block_f(f: int) -> int:
+    """W column tile of the left merges: lane-aligned where f allows
+    (always on a TPU, ``execute._sup_merge``), else whole rows."""
+    return next((bf for bf in (512, 128) if f % bf == 0), f)
 
 
 def delora_gemm(x: jax.Array, w: jax.Array, a: jax.Array, b: jax.Array,
@@ -315,9 +318,7 @@ def householder_gemm_bwd(x: jax.Array, w: jax.Array, u: jax.Array,
     if not execute.supports("householder_gemm", x, w, u):
         dx, dw, du = ref.ref_householder_gemm_bwd(x2, w, u, g2)
         return dx.reshape(x.shape), dw, du
-    bm = 128 if t % 128 == 0 else t
-    bf = 128
-    bk = db * max(1, min(512, d) // db)
+    bm, bf, bk = gemm_tiles(t, d, f, db)
     dx, du = reflect_gemm_dx_pallas(x2, w, u, g2, block_m=bm, block_d=bk,
                                     block_f=bf, interpret=interpret)
     dw = reflect_gemm_dw_pallas(x2, u, g2, block_m=bm, block_d=bk,
@@ -370,8 +371,8 @@ def ether_merge_bwd(w: jax.Array, u: jax.Array, g: jax.Array, *,
     d, f = w.shape
     if not execute.supports("ether_merge", w, u):
         return ref.ref_ether_merge_bwd(w, u, g)
-    bf = 512 if f % 512 == 0 else 128
-    return merge_left_bwd_pallas(w, u, g, block_f=bf, interpret=interpret)
+    return merge_left_bwd_pallas(w, u, g, block_f=_merge_block_f(f),
+                                 interpret=interpret)
 
 
 def etherplus_merge_bwd(w: jax.Array, u1: jax.Array, v1: jax.Array,

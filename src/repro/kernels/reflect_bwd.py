@@ -19,9 +19,10 @@ residuals — no intermediate activations are saved, and nothing is
 re-derived by differentiating the jnp reference.
 
 Grid: (T/block_t,).  dx is tile-local; dL/dû accumulates in a persistent
-f32 VMEM scratch across all row tiles (the TPU grid is sequential on a
-core) and the ε-normalization chain rule is applied once at the final
-step.  VMEM per step ≈ 3·block_t·d·4B + O(d) for the adapter vectors.
+f32 (1, d) VMEM scratch across all row tiles (the TPU grid is sequential
+on a core) and the ε-normalization chain rule is applied once at the
+final step.  The in-kernel math is ``blockwise.update_bwd`` on flat
+tiles; ``norm_chain`` below is its (..., n, db) twin for jnp callers.
 """
 
 from __future__ import annotations
@@ -33,61 +34,43 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import blockwise as bw
 
-def norm_chain(u, ghat, eps: float = 1e-8):
-    """Pull dL/dû back through û = u/(‖u‖+ε) on the last axis (f32).
+
+def norm_chain(u, ghat, eps: float = 1e-8, axis: int = -1):
+    """Pull dL/dû back through û = u/(‖u‖+ε) along ``axis`` (f32).
 
     This is exactly XLA's AD of the reference normalization, so kernel
     backwards that use it agree with ref-AD to rounding error."""
-    r = jnp.sqrt(jnp.sum(u * u, axis=-1, keepdims=True))
+    r = jnp.sqrt(jnp.sum(u * u, axis=axis, keepdims=True))
     s = r + eps
-    dot = jnp.sum(u * ghat, axis=-1, keepdims=True)
+    dot = jnp.sum(u * ghat, axis=axis, keepdims=True)
     return ghat / s - dot * u / (r * s * s)
 
 
-def unit_rows(u, eps: float = 1e-8):
-    """Row-normalize (f32) — matches the forward kernels' û."""
-    return u / (jnp.sqrt(jnp.sum(u * u, axis=-1, keepdims=True)) + eps)
-
-
-def reflect_bwd_tile(xb, gb, un, coeff):
-    """Shared per-tile math: (dx_b, ĝ_u) for one rank-1 direction.
-
-    xb/gb: (T, n, db) f32; un: (n, db) unit rows.  Returns the dx
-    contribution of this direction *excluding* the identity term and the
-    un-normalized dL/dû partial for this tile."""
-    pg = jnp.einsum("tnb,nb->tn", gb, un)
-    px = jnp.einsum("tnb,nb->tn", xb, un)
-    dx_term = coeff * pg[..., None] * un[None]
-    ghat = coeff * (jnp.einsum("tn,tnb->nb", px, gb)
-                    + jnp.einsum("tn,tnb->nb", pg, xb))
-    return dx_term, ghat
-
-
 def _r1_bwd_kernel(u_ref, x_ref, g_ref, dx_ref, du_ref, acc_ref, *,
-                   n: int, db: int, coeff: float):
+                   db: int, coeff: float):
     i = pl.program_id(0)
 
     @pl.when(i == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    u = u_ref[...].astype(jnp.float32)
-    un = unit_rows(u)
-    tm = x_ref.shape[0]
-    xb = x_ref[...].astype(jnp.float32).reshape(tm, n, db)
-    gb = g_ref[...].astype(jnp.float32).reshape(tm, n, db)
-    dx_term, ghat = reflect_bwd_tile(xb, gb, un, coeff)
-    dx_ref[...] = (gb + dx_term).reshape(tm, n * db).astype(dx_ref.dtype)
+    e = bw.block_matrix(x_ref.shape[1], db)
+    u = u_ref[...].astype(jnp.float32)                       # (1, d)
+    dx, (ghat,) = bw.update_bwd(x_ref[...].astype(jnp.float32),
+                                g_ref[...].astype(jnp.float32),
+                                [(bw.unit(u, e), coeff)], e)
+    dx_ref[...] = dx.astype(dx_ref.dtype)
     acc_ref[...] += ghat
 
     @pl.when(i == pl.num_programs(0) - 1)
     def _done():
-        du_ref[...] = norm_chain(u, acc_ref[...]).astype(du_ref.dtype)
+        du_ref[...] = bw.norm_chain(u, acc_ref[...], e).astype(du_ref.dtype)
 
 
 def _r2_bwd_kernel(u_ref, v_ref, x_ref, g_ref, dx_ref, du_ref, dv_ref,
-                   accu_ref, accv_ref, *, n: int, db: int):
+                   accu_ref, accv_ref, *, db: int):
     i = pl.program_id(0)
 
     @pl.when(i == 0)
@@ -95,22 +78,20 @@ def _r2_bwd_kernel(u_ref, v_ref, x_ref, g_ref, dx_ref, du_ref, dv_ref,
         accu_ref[...] = jnp.zeros_like(accu_ref)
         accv_ref[...] = jnp.zeros_like(accv_ref)
 
+    e = bw.block_matrix(x_ref.shape[1], db)
     u = u_ref[...].astype(jnp.float32)
     v = v_ref[...].astype(jnp.float32)
-    un, vn = unit_rows(u), unit_rows(v)
-    tm = x_ref.shape[0]
-    xb = x_ref[...].astype(jnp.float32).reshape(tm, n, db)
-    gb = g_ref[...].astype(jnp.float32).reshape(tm, n, db)
-    dxu, ghu = reflect_bwd_tile(xb, gb, un, -1.0)
-    dxv, ghv = reflect_bwd_tile(xb, gb, vn, +1.0)
-    dx_ref[...] = (gb + dxu + dxv).reshape(tm, n * db).astype(dx_ref.dtype)
+    dx, (ghu, ghv) = bw.update_bwd(
+        x_ref[...].astype(jnp.float32), g_ref[...].astype(jnp.float32),
+        [(bw.unit(u, e), -1.0), (bw.unit(v, e), 1.0)], e)
+    dx_ref[...] = dx.astype(dx_ref.dtype)
     accu_ref[...] += ghu
     accv_ref[...] += ghv
 
     @pl.when(i == pl.num_programs(0) - 1)
     def _done():
-        du_ref[...] = norm_chain(u, accu_ref[...]).astype(du_ref.dtype)
-        dv_ref[...] = norm_chain(v, accv_ref[...]).astype(dv_ref.dtype)
+        du_ref[...] = bw.norm_chain(u, accu_ref[...], e).astype(du_ref.dtype)
+        dv_ref[...] = bw.norm_chain(v, accv_ref[...], e).astype(dv_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("block_t", "interpret"))
@@ -125,25 +106,27 @@ def ether_reflect_bwd_pallas(x: jax.Array, u: jax.Array, g: jax.Array, *,
     assert n * db == d and g.shape == x.shape
     block_t = largest_divisor(t, block_t)
     grid = (t // block_t,)
-    return pl.pallas_call(
-        functools.partial(_r1_bwd_kernel, n=n, db=db, coeff=-2.0),
+    dx, du = pl.pallas_call(
+        functools.partial(_r1_bwd_kernel, db=db, coeff=-2.0),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((n, db), lambda i: (0, 0)),
+            pl.BlockSpec((1, d), lambda i: (0, 0)),
             pl.BlockSpec((block_t, d), lambda i: (i, 0)),
             pl.BlockSpec((block_t, d), lambda i: (i, 0)),
         ],
         out_specs=[
             pl.BlockSpec((block_t, d), lambda i: (i, 0)),
-            pl.BlockSpec((n, db), lambda i: (0, 0)),
+            pl.BlockSpec((1, d), lambda i: (0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((t, d), x.dtype),
-            jax.ShapeDtypeStruct((n, db), u.dtype),
+            jax.ShapeDtypeStruct((1, d), u.dtype),
         ],
-        scratch_shapes=[pltpu.VMEM((n, db), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((1, d), jnp.float32)],
+        compiler_params=bw.ROW_VMEM,
         interpret=interpret,
-    )(u, x, g)
+    )(u.reshape(1, d), x, g)
+    return dx, du.reshape(n, db)
 
 
 @functools.partial(jax.jit, static_argnames=("block_t", "interpret"))
@@ -158,26 +141,24 @@ def etherplus_reflect_bwd_pallas(x: jax.Array, u: jax.Array, v: jax.Array,
     assert n * db == d and u.shape == v.shape and g.shape == x.shape
     block_t = largest_divisor(t, block_t)
     grid = (t // block_t,)
-    return pl.pallas_call(
-        functools.partial(_r2_bwd_kernel, n=n, db=db),
+    row = pl.BlockSpec((1, d), lambda i: (0, 0))
+    dx, du, dv = pl.pallas_call(
+        functools.partial(_r2_bwd_kernel, db=db),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((n, db), lambda i: (0, 0)),
-            pl.BlockSpec((n, db), lambda i: (0, 0)),
+            row, row,
             pl.BlockSpec((block_t, d), lambda i: (i, 0)),
             pl.BlockSpec((block_t, d), lambda i: (i, 0)),
         ],
-        out_specs=[
-            pl.BlockSpec((block_t, d), lambda i: (i, 0)),
-            pl.BlockSpec((n, db), lambda i: (0, 0)),
-            pl.BlockSpec((n, db), lambda i: (0, 0)),
-        ],
+        out_specs=[pl.BlockSpec((block_t, d), lambda i: (i, 0)), row, row],
         out_shape=[
             jax.ShapeDtypeStruct((t, d), x.dtype),
-            jax.ShapeDtypeStruct((n, db), u.dtype),
-            jax.ShapeDtypeStruct((n, db), v.dtype),
+            jax.ShapeDtypeStruct((1, d), u.dtype),
+            jax.ShapeDtypeStruct((1, d), v.dtype),
         ],
-        scratch_shapes=[pltpu.VMEM((n, db), jnp.float32),
-                        pltpu.VMEM((n, db), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((1, d), jnp.float32),
+                        pltpu.VMEM((1, d), jnp.float32)],
+        compiler_params=bw.ROW_VMEM,
         interpret=interpret,
-    )(u, v, x, g)
+    )(u.reshape(1, d), v.reshape(1, d), x, g)
+    return dx, du.reshape(n, db), dv.reshape(n, db)

@@ -13,8 +13,9 @@ chain rule is linear in dL/dû and all sequences with the same tenant id
 share one bank row.  This reproduces ref-AD's gather-vjp exactly, so
 duplicate tenant ids accumulate rather than overwrite.
 
-Grid: (B, S/block_s).  ĝ_seq rides in a persistent (n, db) f32 scratch,
-re-zeroed at each sequence's first S tile and emitted at its last.
+Grid: (B, S/block_s).  The banks ride flat as (A, 1, d) rows; ĝ_seq
+rides in a persistent (1, d) f32 scratch, re-zeroed at each sequence's
+first S tile and emitted at its last.
 """
 
 from __future__ import annotations
@@ -26,56 +27,66 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.reflect_bwd import reflect_bwd_tile, unit_rows
+from repro.kernels import blockwise as bw
 
 
-def _r1b_bwd_kernel(ids_ref, u_ref, x_ref, g_ref, dx_ref, gu_ref,
-                    acc_ref, *, n: int, db: int):
+def _bank_bwd_kernel(ids_ref, *refs, db: int, coeffs):
+    """Shared body: refs = (*adapter rows, x, g, dx, *ĝ outs, *accs),
+    one adapter row / ĝ output / accumulator per (direction, coeff)."""
     del ids_ref  # consumed by the index maps
+    m = len(coeffs)
+    u_refs, (x_ref, g_ref, dx_ref) = refs[:m], refs[m:m + 3]
+    gu_refs, acc_refs = refs[m + 3:2 * m + 3], refs[2 * m + 3:]
     j = pl.program_id(1)
 
     @pl.when(j == 0)
     def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+        for acc in acc_refs:
+            acc[...] = jnp.zeros_like(acc)
 
-    un = unit_rows(u_ref[0].astype(jnp.float32))
-    bs = x_ref.shape[1]
-    xb = x_ref[0].astype(jnp.float32).reshape(bs, n, db)
-    gb = g_ref[0].astype(jnp.float32).reshape(bs, n, db)
-    term, ghat = reflect_bwd_tile(xb, gb, un, -2.0)
-    dx_ref[0] = (gb + term).reshape(bs, n * db).astype(dx_ref.dtype)
-    acc_ref[...] += ghat
-
-    @pl.when(j == pl.num_programs(1) - 1)
-    def _emit():
-        gu_ref[0] = acc_ref[...]
-
-
-def _r2b_bwd_kernel(ids_ref, u_ref, v_ref, x_ref, g_ref, dx_ref, gu_ref,
-                    gv_ref, accu_ref, accv_ref, *, n: int, db: int):
-    del ids_ref
-    j = pl.program_id(1)
-
-    @pl.when(j == 0)
-    def _init():
-        accu_ref[...] = jnp.zeros_like(accu_ref)
-        accv_ref[...] = jnp.zeros_like(accv_ref)
-
-    un = unit_rows(u_ref[0].astype(jnp.float32))
-    vn = unit_rows(v_ref[0].astype(jnp.float32))
-    bs = x_ref.shape[1]
-    xb = x_ref[0].astype(jnp.float32).reshape(bs, n, db)
-    gb = g_ref[0].astype(jnp.float32).reshape(bs, n, db)
-    tu, ghu = reflect_bwd_tile(xb, gb, un, -1.0)
-    tv, ghv = reflect_bwd_tile(xb, gb, vn, +1.0)
-    dx_ref[0] = (gb + tu + tv).reshape(bs, n * db).astype(dx_ref.dtype)
-    accu_ref[...] += ghu
-    accv_ref[...] += ghv
+    e = bw.block_matrix(x_ref.shape[2], db)
+    dirs = [(bw.unit(r[0].astype(jnp.float32), e), c)
+            for r, c in zip(u_refs, coeffs)]
+    dx, ghats = bw.update_bwd(x_ref[0].astype(jnp.float32),
+                              g_ref[0].astype(jnp.float32), dirs, e)
+    dx_ref[0] = dx.astype(dx_ref.dtype)
+    for acc, ghat in zip(acc_refs, ghats):
+        acc[...] += ghat
 
     @pl.when(j == pl.num_programs(1) - 1)
     def _emit():
-        gu_ref[0] = accu_ref[...]
-        gv_ref[0] = accv_ref[...]
+        for out, acc in zip(gu_refs, acc_refs):
+            out[0] = acc[...]
+
+
+def _bank_bwd(x, banks, ids, g, coeffs, block_s, interpret):
+    """dx and per-sequence ĝ partials (B, n, db) for each bank."""
+    from repro.core.execute import _interpret, largest_divisor
+    b, s, d = x.shape
+    a, n, db = banks[0].shape
+    assert n * db == d and g.shape == x.shape
+    assert all(bk.shape == banks[0].shape for bk in banks)
+    block_s = largest_divisor(s, block_s)
+    row_in = pl.BlockSpec((1, 1, d), lambda i, j, ids_ref: (ids_ref[i], 0, 0))
+    row_out = pl.BlockSpec((1, 1, d), lambda i, j, ids_ref: (i, 0, 0))
+    tile = pl.BlockSpec((1, block_s, d), lambda i, j, ids_ref: (i, j, 0))
+    m = len(banks)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(b, s // block_s),
+        in_specs=[row_in] * m + [tile, tile],
+        out_specs=[tile] + [row_out] * m,
+        scratch_shapes=[pltpu.VMEM((1, d), jnp.float32)] * m,
+    )
+    dx, *ghats = pl.pallas_call(
+        functools.partial(_bank_bwd_kernel, db=db, coeffs=coeffs),
+        grid_spec=grid_spec,
+        out_shape=[jax.ShapeDtypeStruct((b, s, d), x.dtype)]
+        + [jax.ShapeDtypeStruct((b, 1, d), jnp.float32)] * m,
+        compiler_params=bw.ROW_VMEM,
+        interpret=_interpret(interpret),
+    )(ids.astype(jnp.int32), *(bk.reshape(a, 1, d) for bk in banks), x, g)
+    return (dx, *(gh.reshape(b, n, db) for gh in ghats))
 
 
 @functools.partial(jax.jit, static_argnames=("block_s", "interpret"))
@@ -85,33 +96,7 @@ def ether_reflect_batched_bwd_pallas(x: jax.Array, u_bank: jax.Array,
                                      interpret: bool | None = None):
     """x/g: (B, S, d); u_bank: (A, n, db); ids: (B,).
     Returns (dx, ĝ_seq (B, n, db) f32 un-normalized partials)."""
-    from repro.core.execute import _interpret, largest_divisor
-    b, s, d = x.shape
-    _, n, db = u_bank.shape
-    assert n * db == d and g.shape == x.shape
-    block_s = largest_divisor(s, block_s)
-    grid = (b, s // block_s)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, n, db), lambda i, j, ids_ref: (ids_ref[i], 0, 0)),
-            pl.BlockSpec((1, block_s, d), lambda i, j, ids_ref: (i, j, 0)),
-            pl.BlockSpec((1, block_s, d), lambda i, j, ids_ref: (i, j, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block_s, d), lambda i, j, ids_ref: (i, j, 0)),
-            pl.BlockSpec((1, n, db), lambda i, j, ids_ref: (i, 0, 0)),
-        ],
-        scratch_shapes=[pltpu.VMEM((n, db), jnp.float32)],
-    )
-    return pl.pallas_call(
-        functools.partial(_r1b_bwd_kernel, n=n, db=db),
-        grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((b, s, d), x.dtype),
-                   jax.ShapeDtypeStruct((b, n, db), jnp.float32)],
-        interpret=_interpret(interpret),
-    )(ids.astype(jnp.int32), u_bank, x, g)
+    return _bank_bwd(x, (u_bank,), ids, g, (-2.0,), block_s, interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("block_s", "interpret"))
@@ -121,34 +106,5 @@ def etherplus_reflect_batched_bwd_pallas(x: jax.Array, u_bank: jax.Array,
                                          block_s: int = 128,
                                          interpret: bool | None = None):
     """Rank-2 bank reflect backward.  Returns (dx, ĝu_seq, ĝv_seq)."""
-    from repro.core.execute import _interpret, largest_divisor
-    b, s, d = x.shape
-    _, n, db = u_bank.shape
-    assert n * db == d and u_bank.shape == v_bank.shape
-    block_s = largest_divisor(s, block_s)
-    grid = (b, s // block_s)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, n, db), lambda i, j, ids_ref: (ids_ref[i], 0, 0)),
-            pl.BlockSpec((1, n, db), lambda i, j, ids_ref: (ids_ref[i], 0, 0)),
-            pl.BlockSpec((1, block_s, d), lambda i, j, ids_ref: (i, j, 0)),
-            pl.BlockSpec((1, block_s, d), lambda i, j, ids_ref: (i, j, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block_s, d), lambda i, j, ids_ref: (i, j, 0)),
-            pl.BlockSpec((1, n, db), lambda i, j, ids_ref: (i, 0, 0)),
-            pl.BlockSpec((1, n, db), lambda i, j, ids_ref: (i, 0, 0)),
-        ],
-        scratch_shapes=[pltpu.VMEM((n, db), jnp.float32),
-                        pltpu.VMEM((n, db), jnp.float32)],
-    )
-    return pl.pallas_call(
-        functools.partial(_r2b_bwd_kernel, n=n, db=db),
-        grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((b, s, d), x.dtype),
-                   jax.ShapeDtypeStruct((b, n, db), jnp.float32),
-                   jax.ShapeDtypeStruct((b, n, db), jnp.float32)],
-        interpret=_interpret(interpret),
-    )(ids.astype(jnp.int32), u_bank, v_bank, x, g)
+    return _bank_bwd(x, (u_bank, v_bank), ids, g, (-1.0, 1.0), block_s,
+                     interpret)

@@ -445,6 +445,7 @@ def main():
 
     import jax
     import jax.numpy as jnp
+    from repro.common import compile_cache
     from repro.configs import get_config, peft_targets
     from repro.core import execute, methods
     from repro.core.peft import (init_adapter_bank, init_adapters,
@@ -453,6 +454,7 @@ def main():
     from repro.models import EncDecConfig, init_model
 
     methods.get(args.method)   # typed UnknownMethodError on bad names
+    compile_cache.enable()
     cfg = get_config(args.arch, args.variant)
     peft = PEFTConfig(method=args.method, n_blocks=args.n_blocks,
                       targets=peft_targets(args.arch),

@@ -32,6 +32,10 @@ def build_argparser():
     ap.add_argument("--rank", type=int, default=8)
     ap.add_argument("--peft-mode", default="activation",
                     choices=["activation", "weight", "blockgemm"])
+    ap.add_argument("--backend", default="auto",
+                    choices=("jnp", "pallas", "auto"),
+                    help="execution backend for the adapter hot ops "
+                         "(core.execute; auto = Pallas where shapes tile)")
     ap.add_argument("--lr", type=float, default=2e-3)
     ap.add_argument("--schedule", default="cosine",
                     choices=["cosine", "wsd", "constant"])
@@ -69,7 +73,7 @@ def run(args) -> dict:
     peft = None if full_ft else PEFTConfig(
         method=args.method, n_blocks=args.n_blocks, rank=args.rank,
         alpha=float(args.rank), mode=args.peft_mode,
-        targets=peft_targets(args.arch))
+        targets=peft_targets(args.arch), backend=args.backend)
 
     sched = {"cosine": lambda: cosine(args.lr, args.steps, args.warmup),
              "wsd": lambda: wsd(args.lr, args.steps, args.warmup),
@@ -96,7 +100,10 @@ def run(args) -> dict:
 
 
 def main():
-    run(build_argparser().parse_args())
+    from repro.common import compile_cache
+    args = build_argparser().parse_args()
+    compile_cache.enable()
+    run(args)
 
 
 if __name__ == "__main__":

@@ -23,12 +23,12 @@ Params = dict[str, Any]
 
 def he_normal(rng, shape, dtype, fan_in=None):
     fan_in = fan_in or shape[-2] if len(shape) >= 2 else shape[0]
-    return jax.random.normal(rng, shape, dtype) * np.sqrt(2.0 / fan_in)
+    return jax.random.normal(rng, shape, dtype) * float(np.sqrt(2.0 / fan_in))
 
 
 def lecun_normal(rng, shape, dtype, fan_in=None):
     fan_in = fan_in or (shape[-2] if len(shape) >= 2 else shape[0])
-    return jax.random.normal(rng, shape, dtype) * np.sqrt(1.0 / fan_in)
+    return jax.random.normal(rng, shape, dtype) * float(np.sqrt(1.0 / fan_in))
 
 
 def init_dense(rng, d_in: int, d_out: int, dtype, *, bias: bool = False,

@@ -29,10 +29,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-try:
-    from jax.experimental.shard_map import shard_map
-except ImportError:                                  # newer jax
-    from jax.shard_map import shard_map              # type: ignore
+from jax import shard_map
 
 from repro.core.peft import get_adapter
 from repro.models.layers import ACTS
@@ -168,7 +165,7 @@ def moe_mlp_a2a(p: Params, x: jax.Array, *, top_k: int, n_experts: int,
         out_specs=(P(dp, "model", None),
                    {"aux_loss": P(), "router_z": P(),
                     "dropped_frac": P()}),
-        check_rep=False)
+        check_vma=False)
 
     return fn(x, p["router"]["kernel"], p["gate_proj"]["kernel"],
               p["up_proj"]["kernel"], p["down_proj"]["kernel"],

@@ -26,10 +26,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-try:
-    from jax.experimental.shard_map import shard_map
-except ImportError:                                  # newer jax
-    from jax.shard_map import shard_map              # type: ignore
+from jax import shard_map
 
 
 def pipeline_apply(stage_fn: Callable, stage_params: Any, x: jax.Array,
@@ -91,5 +88,5 @@ def pipeline_apply(stage_fn: Callable, stage_params: Any, x: jax.Array,
         body, mesh=mesh,
         in_specs=(P(stage_axis), P()),
         out_specs=P(),
-        check_rep=False)
+        check_vma=False)
     return fn(stage_params, x)

@@ -51,7 +51,6 @@ def _sizes(mesh: Mesh):
 _IN_PROJ = re.compile(
     r"(q_proj|k_proj|v_proj|gate_proj|up_proj|in_proj|in_x|in_y|mm_proj/up_proj"
     r"|router)/kernel$")
-_ATTN_QKV = re.compile(r"(q_proj|k_proj|v_proj)/kernel$")
 _OUT_PROJ = re.compile(
     r"(o_proj|down_proj|out_proj|mm_proj/down_proj)/kernel$")
 _EXPERT = re.compile(r"(gate_proj|up_proj|down_proj)/kernel$")
@@ -118,14 +117,6 @@ def spec_for_param(path: str, shape: tuple[int, ...], mesh: Mesh,
         return pick((None, "model"))                 # (.., W, C)
     if path.endswith("conv/bias"):
         return pick(("model",))
-    if serve and _ATTN_QKV.search(path):
-        # DESIGN.md §14: attention in-projections replicate in serve.
-        # Their output axis is head-structured, and a model split that
-        # crosses head boundaries (n_kv as low as 1 in the zoo) forces
-        # a resharding head reshape that XLA CPU miscomputes on 2-D
-        # meshes when the data axis is idle (batch-1 prefill).  o_proj
-        # and the MLP carry the model axis instead.
-        return P()
     if _OUT_PROJ.search(path):                       # (.., d_proj, d)
         return pick(("model", dp), ("model", None), (None, dp))
     if _IN_PROJ.search(path):                        # (.., d, d_proj)

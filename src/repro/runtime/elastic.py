@@ -38,4 +38,5 @@ def remesh(n_devices: Optional[int] = None, *, prefer_model: int = 16):
     n = n_devices or len(devs)
     data, model = best_mesh_shape(n, prefer_model=prefer_model)
     return jax.make_mesh((data, model), ("data", "model"),
-                         devices=devs[:data * model])
+                         devices=devs[:data * model],
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)

@@ -835,6 +835,10 @@ class ServeEngine:
         # hot tenant — promotions/demotions mid-trace never retrace
         state2, _, _ = self._merged_step_fn(self.params, state)
         jax.block_until_ready(state2["tok"])
+        # the fail/cancel paths mask a slot off eagerly (_fail_slot)
+        jax.block_until_ready(
+            self._pin("active", state["active"].at[0].set(False)))
+        self.registry.warm_scrub()                     # quarantine scrub
         self.registry.warm_init()                      # warms init_fn
         self.registry.warm_swap()                      # warms _swap
         self.registry.warm_merge()                     # warms _merge

@@ -65,6 +65,7 @@ from repro.core.peft import (AdapterBank, MergedCache,
                              init_adapters, merge_params,
                              validate_tenant_ids)
 from repro.core.transforms import PEFTConfig
+from repro.parallel.context import MeshContext, mesh_context
 from repro.serving.persistence import (MethodMismatchError,
                                        StoreCorruptionError)
 from repro.serving.scheduler import QuarantineError
@@ -219,7 +220,11 @@ class AdapterRegistry:
             # first is a jit cache hit — the merge ops are charged once
             # per promotion, the compile once ever
             self.stats["merge_traces"] += 1
-            return merge_params(base, tree, self._peft)
+            if self._mesh is None:
+                return merge_params(base, tree, self._peft)
+            # the merge kernels read the mesh (core.execute.supports)
+            with mesh_context(MeshContext(self._mesh, seq_shard=False)):
+                return merge_params(base, tree, self._peft)
 
         self._merge = (jax.jit(_merge_impl) if merge_out is None else
                        jax.jit(_merge_impl, out_shardings=merge_out))
@@ -818,6 +823,14 @@ class AdapterRegistry:
         tree = self.adapters_for(0)
         discard = self._swap(self.bank, self._to_mesh(tree), jnp.int32(0))
         jax.block_until_ready(jax.tree_util.tree_leaves(discard.tree)[0])
+
+    def warm_scrub(self) -> None:
+        """Compile the eager ops of the quarantine scrub (a bank row read
+        back, then its identity tree) so the first quarantine after
+        warmup compiles nothing."""
+        ident = _methods.identity_like(self._peft.method,
+                                       self.bank.select(0))
+        jax.block_until_ready(jax.tree_util.tree_leaves(ident)[0])
 
     def warm_merge(self) -> None:
         """Compile the jitted merge on a throwaway tree so the first

@@ -255,12 +255,12 @@ def test_bwd_dispatch_backends_agree():
 
 
 def test_bwd_non_tiling_shapes_fall_back_truthfully():
-    """Non-divisor d (30 = 5×6 blocks) tiles nothing: `auto` resolves the
-    backward to ref-AD and counts it as *_bwd.jnp — never a silent wrong
-    kernel."""
+    """An odd f (600: not 128-aligned, wider than a whole-row tile)
+    tiles nothing: `auto` resolves the backward to ref-AD and counts it
+    as *_bwd.jnp — never a silent wrong kernel."""
     t, d, n = 7, 30, 5
     x = _rand(RNG, (t, d))
-    w = _rand(jax.random.fold_in(RNG, 1), (d, 17))
+    w = _rand(jax.random.fold_in(RNG, 1), (d, 600))
     u = _rand(jax.random.fold_in(RNG, 2), (n, d // n))
 
     def loss(u, backend):

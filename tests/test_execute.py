@@ -41,16 +41,23 @@ def test_unknown_backend_rejected():
                          jnp.ones((4, 8)), jnp.ones((2, 4)))
 
 
-def test_auto_selects_pallas_on_tileable_jnp_on_odd():
+def test_auto_selects_pallas_on_tileable_jnp_on_odd(monkeypatch):
     x_good = jnp.ones((128, 256))
     w_good = jnp.ones((256, 128))
     u_good = jnp.ones((8, 32))
     assert execute.selected_backend(
         "householder_gemm", "auto", x_good, w_good, u_good) == "pallas"
-    # odd f dimension cannot tile the MXU
+    # an odd f wider than a whole-row tile tiles nowhere
+    w_wide = jnp.ones((256, 600))
+    assert execute.selected_backend(
+        "householder_gemm", "auto", x_good, w_wide, u_good) == "jnp"
+    # on a TPU an odd f dimension cannot tile the MXU lanes at all
     w_odd = jnp.ones((256, 130))
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     assert execute.selected_backend(
         "householder_gemm", "auto", x_good, w_odd, u_good) == "jnp"
+    assert execute.selected_backend(
+        "householder_gemm", "auto", x_good, w_good, u_good) == "pallas"
 
 
 def test_dispatch_counters_track_trace_counts():
@@ -105,7 +112,8 @@ def test_adapted_dense_auto_executes_pallas_on_tileable_shapes():
 
 
 def test_adapted_dense_auto_falls_back_on_odd_shapes():
-    d, f, n = 30, 17, 5
+    # f=600: neither 128-aligned nor a whole-row tile (<= 512)
+    d, f, n = 30, 600, 5
     a = init_adapter(RNG, "ether", d, f,
                      PEFTConfig(method="ether", n_blocks=n))
     x = jax.random.normal(jax.random.PRNGKey(1), (7, d))

@@ -81,7 +81,8 @@ def test_collectives_counted_with_trips(subproc):
 import jax, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P, NamedSharding
 from repro.launch.hlo_analysis import analyze_hlo
-mesh = jax.make_mesh((4,), ("data",))
+mesh = jax.make_mesh((4,), ("data",),
+                     axis_types=(jax.sharding.AxisType.Auto,))
 x = jax.ShapeDtypeStruct((64, 64), jnp.float32)
 w = jax.ShapeDtypeStruct((8, 64, 64), jnp.float32)
 

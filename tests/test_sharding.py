@@ -147,7 +147,7 @@ def test_compressed_psum_shard_map(subproc):
     out = subproc("""
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from repro.launch.mesh import make_host_mesh
 from repro.runtime.compression import compressed_psum
 
@@ -214,7 +214,8 @@ import jax, jax.numpy as jnp, numpy as np
 from repro.parallel.pipeline import pipeline_apply
 
 S, B, D, M = 4, 8, 16, 4
-mesh = jax.make_mesh((S,), ("stage",))
+mesh = jax.make_mesh((S,), ("stage",),
+                     axis_types=(jax.sharding.AxisType.Auto,))
 ws = jax.random.normal(jax.random.PRNGKey(0), (S, D, D)) / jnp.sqrt(D)
 x = jax.random.normal(jax.random.PRNGKey(1), (B, D))
 
