@@ -373,21 +373,26 @@ def adapted_dense(x: jax.Array, W: jax.Array, b: Optional[jax.Array],
 
     With ``adapter=None`` (or empty) this is a plain dense layer.
     Dispatches on cfg.method and cfg.mode. x: (..., d_in); W: (d_in, d_out).
+    Where an adapter applies, the work runs under the named scope
+    ``adapted_dense`` (its backward too: the custom_vjp backwards are
+    traced inside the forward's scope), so a profile finds the adapted
+    projections whichever backend or kernel computes them.
     """
     if not adapter or cfg is None or cfg.method == "full":
         y = x @ W.astype(x.dtype)
         return y if b is None else y + b.astype(x.dtype)
 
     m = _methods.get(cfg.method)
-    if "ids" in adapter:
-        # Multi-tenant bank (core.peft.AdapterBank): every adapter leaf
-        # is the whole (num_adapters, ...) bank; each batch row applies
-        # its own tenant's adapter (DESIGN.md §2).
-        _check_bank_inputs(x, adapter, cfg)
-        y = m.bank_dense(x, W, adapter, cfg)
-    else:
-        y = m.dense(x, W, adapter, cfg)
-    return y if b is None else y + b.astype(x.dtype)
+    with jax.named_scope("adapted_dense"):
+        if "ids" in adapter:
+            # Multi-tenant bank (core.peft.AdapterBank): every adapter
+            # leaf is the whole (num_adapters, ...) bank; each batch row
+            # applies its own tenant's adapter (DESIGN.md §2).
+            _check_bank_inputs(x, adapter, cfg)
+            y = m.bank_dense(x, W, adapter, cfg)
+        else:
+            y = m.dense(x, W, adapter, cfg)
+        return y if b is None else y + b.astype(x.dtype)
 
 
 def _check_bank_inputs(x: jax.Array, adapter: Params,

@@ -17,7 +17,6 @@ from typing import Any, Optional
 import jax
 import jax.numpy as jnp
 
-from repro.core.peft import get_adapter
 from repro.models.layers import dense, init_dense, rope
 
 Params = dict[str, Any]
@@ -129,13 +128,10 @@ def apply_attention(p: Params, x: jax.Array, *, n_heads: int, n_kv: int,
       (out, updated_cache).
     * cross-attention: pass ``kv_x`` (encoder states); no cache update.
     """
-    q = dense(p["q_proj"], x, adapter=get_adapter(adapters, "q_proj"),
-              peft=peft)
+    q = dense(p, "q_proj", x, adapters=adapters, peft=peft)
     src = x if kv_x is None else kv_x
-    k = dense(p["k_proj"], src, adapter=get_adapter(adapters, "k_proj"),
-              peft=peft)
-    v = dense(p["v_proj"], src, adapter=get_adapter(adapters, "v_proj"),
-              peft=peft)
+    k = dense(p, "k_proj", src, adapters=adapters, peft=peft)
+    v = dense(p, "v_proj", src, adapters=adapters, peft=peft)
     from repro.parallel.context import shard_heads
     q = shard_heads(_split_heads(q, n_heads), "q")
     k = shard_heads(_split_heads(k, n_kv), "kv")
@@ -153,53 +149,56 @@ def apply_attention(p: Params, x: jax.Array, *, n_heads: int, n_kv: int,
     if cache is not None:
         # decode: write new kv at cache_pos, attend over whole cache.
         # Sliding-window layers use a ring buffer (T == window): slot
-        # i holds absolute position pos − ((pos − i) mod T).
-        t_cache = cache["k"].shape[2]
-        ring = window is not None and t_cache == window
-        write_pos = cache_pos % t_cache if ring else cache_pos
-        if jnp.ndim(cache_pos) == 1:
-            # Per-slot cursors (continuous batching): row b writes its
-            # token at its own time index write_pos[b].  Advanced-index
-            # scatter; decode is single-token per step by construction.
-            if k.shape[2] != 1:
-                raise ValueError("per-slot cache_pos requires "
-                                 "single-token decode steps")
-            bidx = jnp.arange(k.shape[0])
-            ck = cache["k"].at[bidx, :, write_pos, :].set(
-                k[:, :, 0, :].astype(cache["k"].dtype))
-            cv = cache["v"].at[bidx, :, write_pos, :].set(
-                v[:, :, 0, :].astype(cache["v"].dtype))
-        else:
-            ck = jax.lax.dynamic_update_slice_in_dim(cache["k"], k.astype(
-                cache["k"].dtype), write_pos, axis=2)
-            cv = jax.lax.dynamic_update_slice_in_dim(cache["v"], v.astype(
-                cache["v"].dtype), write_pos, axis=2)
-        # keep the post-scatter cache in the layout the serve engine
-        # committed it with (slots→data, kv-heads/T→model) — otherwise
-        # the per-token scatter would let GSPMD drift the layout and the
-        # next decode step's input signature (a retrace under a mesh)
-        from repro.parallel.context import shard_slot_cache
-        ck = shard_slot_cache(ck, "kv")
-        cv = shard_slot_cache(cv, "kv")
-        qpos = positions[:, -1:]                     # (B, 1) absolute pos
-        kpos = None
-        if ring:
-            slots = jnp.arange(t_cache)
-            kpos = qpos[..., None] - ((qpos[..., None] - slots[None, None])
-                                      % t_cache)     # (B, 1, T) absolute
-        out = _decode_attend(q, ck, cv, qpos, causal=causal, window=window,
-                             kpos=kpos)
+        # i holds absolute position pos − ((pos − i) mod T).  The KV
+        # write and the attend run under the scope ``attn.decode``.
+        with jax.named_scope("attn.decode"):
+            t_cache = cache["k"].shape[2]
+            ring = window is not None and t_cache == window
+            write_pos = cache_pos % t_cache if ring else cache_pos
+            if jnp.ndim(cache_pos) == 1:
+                # Per-slot cursors (continuous batching): row b writes
+                # its token at its own time index write_pos[b].
+                # Advanced-index scatter; decode is single-token per
+                # step by construction.
+                if k.shape[2] != 1:
+                    raise ValueError("per-slot cache_pos requires "
+                                     "single-token decode steps")
+                bidx = jnp.arange(k.shape[0])
+                ck = cache["k"].at[bidx, :, write_pos, :].set(
+                    k[:, :, 0, :].astype(cache["k"].dtype))
+                cv = cache["v"].at[bidx, :, write_pos, :].set(
+                    v[:, :, 0, :].astype(cache["v"].dtype))
+            else:
+                ck = jax.lax.dynamic_update_slice_in_dim(
+                    cache["k"], k.astype(cache["k"].dtype), write_pos, axis=2)
+                cv = jax.lax.dynamic_update_slice_in_dim(
+                    cache["v"], v.astype(cache["v"].dtype), write_pos, axis=2)
+            # keep the post-scatter cache in the layout the serve engine
+            # committed it with (slots→data, kv-heads/T→model) —
+            # otherwise the per-token scatter would let GSPMD drift the
+            # layout and the next decode step's input signature (a
+            # retrace under a mesh)
+            from repro.parallel.context import shard_slot_cache
+            ck = shard_slot_cache(ck, "kv")
+            cv = shard_slot_cache(cv, "kv")
+            qpos = positions[:, -1:]                 # (B, 1) absolute pos
+            kpos = None
+            if ring:
+                slots = jnp.arange(t_cache)
+                kpos = qpos[..., None] - (
+                    (qpos[..., None] - slots[None, None])
+                    % t_cache)                       # (B, 1, T) absolute
+            out = _decode_attend(q, ck, cv, qpos, causal=causal,
+                                 window=window, kpos=kpos)
         out = _merge_heads(out)
-        out = dense(p["o_proj"], out, adapter=get_adapter(adapters, "o_proj"),
-                    peft=peft)
+        out = dense(p, "o_proj", out, adapters=adapters, peft=peft)
         return out, {"k": ck, "v": cv}
 
     out = attention_core(q, k, v, causal=causal, window=window,
                          q_offset=q_offset, q_chunk=q_chunk)
     out = shard_heads(out, "out")
     out = _merge_heads(out)
-    out = dense(p["o_proj"], out, adapter=get_adapter(adapters, "o_proj"),
-                peft=peft)
+    out = dense(p, "o_proj", out, adapters=adapters, peft=peft)
     return out, {"k": k, "v": v}
 
 

@@ -146,14 +146,13 @@ def _dec_layer(p, x, cfg: EncDecConfig, *, positions, enc_out=None,
     a_x = get_adapter(adapters, "cross_attn") if adapters else None
     if cross_kv is not None:
         # decode: precomputed cross K/V — bidirectional single-query attend
-        q = L.dense(p["cross_attn"]["q_proj"], h,
-                    adapter=get_adapter(a_x, "q_proj"), peft=peft)
+        q = L.dense(p["cross_attn"], "q_proj", h, adapters=a_x, peft=peft)
         q = _split_heads(q, cfg.n_heads)
         out = _decode_attend(q, cross_kv["k"], cross_kv["v"],
                              jnp.zeros((x.shape[0], 1), jnp.int32),
                              causal=False)
-        out = L.dense(p["cross_attn"]["o_proj"], _merge_heads(out),
-                      adapter=get_adapter(a_x, "o_proj"), peft=peft)
+        out = L.dense(p["cross_attn"], "o_proj", _merge_heads(out),
+                      adapters=a_x, peft=peft)
         new_cross = cross_kv
     else:
         out, new_cross = apply_attention(

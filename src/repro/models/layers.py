@@ -41,15 +41,24 @@ def init_dense(rng, d_in: int, d_out: int, dtype, *, bias: bool = False,
     return p
 
 
-def dense(p: Params, x: jax.Array, *, adapter: Optional[Params] = None,
+def dense(p: Params, key: str, x: jax.Array, *,
+          adapters: Optional[Params] = None,
           peft: Optional[PEFTConfig] = None) -> jax.Array:
-    """y = adapted(W)ᵀx + b — the single PEFT attach point.
+    """y = adapted(W)ᵀx + b for the projection ``p[key]`` — the single
+    PEFT attach point.
 
-    ``peft.backend`` selects the execution backend (jnp / pallas / auto)
-    for the ETHER hot ops; dispatch happens inside ``adapted_dense`` via
-    ``core.execute``, so every model in the zoo inherits the kernel path
-    without signature changes here."""
-    return adapted_dense(x, p["kernel"], p.get("bias"), adapter, peft)
+    ``adapters`` is the adapter tree level that mirrors ``p``; the
+    projection's adapter is ``adapters[key]`` when targeted.  The work
+    runs under a named scope ``key`` (``q_proj``, ``down_proj``, ...), so
+    a profile attributes device time to the projection whatever
+    implements it.  ``peft.backend`` selects the execution backend (jnp /
+    pallas / auto) for the ETHER hot ops; dispatch happens inside
+    ``adapted_dense`` via ``core.execute``, so every model in the zoo
+    inherits the kernel path without signature changes here."""
+    from repro.core.peft import get_adapter
+    with jax.named_scope(key):
+        return adapted_dense(x, p[key]["kernel"], p[key].get("bias"),
+                             get_adapter(adapters, key), peft)
 
 
 # ---------------------------------------------------------------------------
@@ -143,14 +152,10 @@ def init_glu_mlp(rng, d: int, d_ff: int, dtype) -> Params:
 
 def glu_mlp(p: Params, x: jax.Array, act: str = "silu", *,
             adapters=None, peft=None) -> jax.Array:
-    from repro.core.peft import get_adapter
-    g = dense(p["gate_proj"], x, adapter=get_adapter(adapters, "gate_proj"),
-              peft=peft)
-    u = dense(p["up_proj"], x, adapter=get_adapter(adapters, "up_proj"),
-              peft=peft)
+    g = dense(p, "gate_proj", x, adapters=adapters, peft=peft)
+    u = dense(p, "up_proj", x, adapters=adapters, peft=peft)
     h = ACTS[act](g) * u
-    return dense(p["down_proj"], h, adapter=get_adapter(adapters, "down_proj"),
-                 peft=peft)
+    return dense(p, "down_proj", h, adapters=adapters, peft=peft)
 
 
 def init_mlp(rng, d: int, d_ff: int, dtype, *, bias: bool = False) -> Params:
@@ -163,8 +168,5 @@ def init_mlp(rng, d: int, d_ff: int, dtype, *, bias: bool = False) -> Params:
 
 def mlp(p: Params, x: jax.Array, act: str = "gelu", *,
         adapters=None, peft=None) -> jax.Array:
-    from repro.core.peft import get_adapter
-    h = ACTS[act](dense(p["up_proj"], x,
-                        adapter=get_adapter(adapters, "up_proj"), peft=peft))
-    return dense(p["down_proj"], h,
-                 adapter=get_adapter(adapters, "down_proj"), peft=peft)
+    h = ACTS[act](dense(p, "up_proj", x, adapters=adapters, peft=peft))
+    return dense(p, "down_proj", h, adapters=adapters, peft=peft)

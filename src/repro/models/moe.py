@@ -104,10 +104,13 @@ def moe_mlp(p: Params, x: jax.Array, *, top_k: int, n_experts: int,
     # --- per-expert MLP (vmapped; ETHER adapters ride along) ---
     def expert_fn(kg, ku, kd, ag, au, ad, xe):
         from repro.core.transforms import adapted_dense
-        g = adapted_dense(xe, kg, None, ag, peft)
-        u = adapted_dense(xe, ku, None, au, peft)
+        with jax.named_scope("gate_proj"):
+            g = adapted_dense(xe, kg, None, ag, peft)
+        with jax.named_scope("up_proj"):
+            u = adapted_dense(xe, ku, None, au, peft)
         h = ACTS[act](g) * u
-        return adapted_dense(h, kd, None, ad, peft)
+        with jax.named_scope("down_proj"):
+            return adapted_dense(h, kd, None, ad, peft)
 
     ag = get_adapter(adapters, "gate_proj")
     au = get_adapter(adapters, "up_proj")

@@ -121,9 +121,12 @@ def moe_mlp_a2a(p: Params, x: jax.Array, *, top_k: int, n_experts: int,
 
         def expert_fn(g, u, dn, a_g, a_u, a_d, xe):
             from repro.core.transforms import adapted_dense
-            h = ACTS[act](adapted_dense(xe, g, None, a_g, peft)) \
-                * adapted_dense(xe, u, None, a_u, peft)
-            return adapted_dense(h, dn, None, a_d, peft)
+            with jax.named_scope("gate_proj"):
+                h = ACTS[act](adapted_dense(xe, g, None, a_g, peft))
+            with jax.named_scope("up_proj"):
+                h = h * adapted_dense(xe, u, None, a_u, peft)
+            with jax.named_scope("down_proj"):
+                return adapted_dense(h, dn, None, a_d, peft)
 
         y_ec = jax.vmap(expert_fn)(kg, ku, kd, ag, au, ad, buf)
         # (E_l, C2, d)

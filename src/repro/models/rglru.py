@@ -19,7 +19,6 @@ from typing import Any, Optional
 import jax
 import jax.numpy as jnp
 
-from repro.core.peft import get_adapter
 from repro.models.layers import dense, init_dense
 from repro.models.ssm import _causal_conv
 
@@ -101,10 +100,8 @@ def rglru_block(p: Params, x: jax.Array, *, d_rnn: int, n_heads: int,
     differ from the unpadded oracle in the last ulp — the gather keeps
     it bitwise-equal (f32).
     """
-    y_branch = jax.nn.gelu(dense(p["in_y"], x,
-                                 adapter=get_adapter(adapters, "in_y"),
-                                 peft=peft))
-    u = dense(p["in_x"], x, adapter=get_adapter(adapters, "in_x"), peft=peft)
+    y_branch = jax.nn.gelu(dense(p, "in_y", x, adapters=adapters, peft=peft))
+    u = dense(p, "in_x", x, adapters=adapters, peft=peft)
     conv_state = cache["conv"] if cache is not None else None
     u, new_conv = _causal_conv(u, p["conv"]["kernel"], p["conv"]["bias"],
                                conv_state, true_lens=true_lens)
@@ -137,7 +134,6 @@ def rglru_block(p: Params, x: jax.Array, *, d_rnn: int, n_heads: int,
                 axis=1)[:, 0]
 
     out = hs.astype(x.dtype) * y_branch
-    out = dense(p["out_proj"], out, adapter=get_adapter(adapters, "out_proj"),
-                peft=peft)
+    out = dense(p, "out_proj", out, adapters=adapters, peft=peft)
     return out, {"conv": new_conv.astype(x.dtype),
                  "h": final.astype(jnp.float32)}

@@ -16,7 +16,6 @@ from typing import Any, Optional
 import jax
 import jax.numpy as jnp
 
-from repro.core.peft import get_adapter
 from repro.models.layers import dense, init_dense, init_rmsnorm, rmsnorm
 
 Params = dict[str, Any]
@@ -169,8 +168,7 @@ def mamba2_block(p: Params, x: jax.Array, *, d_model: int,
                        dims["d_state"], dims["headdim"])
     B, S, _ = x.shape
 
-    zxbcdt = dense(p["in_proj"], x, adapter=get_adapter(adapters, "in_proj"),
-                   peft=peft)
+    zxbcdt = dense(p, "in_proj", x, adapters=adapters, peft=peft)
     z, xbc, dt_raw = jnp.split(zxbcdt, [di, di + di + 2 * g * n], axis=-1)
 
     conv_state = cache["conv"] if cache is not None else None
@@ -209,8 +207,7 @@ def mamba2_block(p: Params, x: jax.Array, *, d_model: int,
     y = y + p["d_skip"][None, None, :, None] * xh.astype(jnp.float32)
     y = y.reshape(B, S, di).astype(x.dtype)
     y = rmsnorm(p["norm"], y * jax.nn.silu(z))
-    out = dense(p["out_proj"], y, adapter=get_adapter(adapters, "out_proj"),
-                peft=peft)
+    out = dense(p, "out_proj", y, adapters=adapters, peft=peft)
     new_cache = {"conv": new_conv.astype(x.dtype),
                  "ssm": final.astype(jnp.float32)}
     return out, new_cache

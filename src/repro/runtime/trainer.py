@@ -129,35 +129,46 @@ class Trainer:
     def save(self, *, block: bool = False):
         if not self.ckpt:
             return
-        self.ckpt.save(self.step, self.state,
-                       extra={"data": self.data_state.to_dict()},
-                       block=block)
+        with jax.profiler.TraceAnnotation("trainer.checkpoint"):
+            self.ckpt.save(self.step, self.state,
+                           extra={"data": self.data_state.to_dict()},
+                           block=block)
 
     def fit(self, stream, *, steps: int) -> dict:
-        """Run ``steps`` optimizer steps from the stream's cursor."""
+        """Run ``steps`` optimizer steps from the stream's cursor.
+
+        Each iteration is traced as the span ``trainer.step`` (stat
+        ``step``) with children ``trainer.batch`` (reading the batch; its
+        transfer rides in the step's dispatch), ``trainer.fetch`` (the
+        metrics' transfer, which waits for the step) and
+        ``trainer.checkpoint``."""
         ctx = (mesh_context(MeshContext(self.mesh)) if self.mesh is not None
                else _null_ctx())
         last_metrics: dict = {}
         with ctx:
-            while self.step < steps and not self._stop:
-                batch_np = stream.batch_at(self.data_state.step)
-                self._jit_for_batch(batch_np)
-                batch = batch_np
-                self.timer.start()
-                self.state, metrics = self.step_fn(self.state, batch)
-                metrics = {k: float(v) for k, v in metrics.items()}
-                dt = self.timer.stop(self.step)
-                self.data_state.step += 1
-                last_metrics = dict(metrics, step=self.step, step_time=dt)
-                self._log(last_metrics)
-                if self.metrics_hook:
-                    self.metrics_hook(self.step, last_metrics)
-                if self.fail_at_step is not None \
-                        and self.step == self.fail_at_step:
-                    raise RuntimeError(
-                        f"injected failure at step {self.step}")
-                if self.ckpt and self.step % self.ckpt_every == 0:
-                    self.save()
+            while (step := self.step) < steps and not self._stop:
+                with jax.profiler.TraceAnnotation("trainer.step", step=step):
+                    with jax.profiler.TraceAnnotation("trainer.batch"):
+                        batch_np = stream.batch_at(self.data_state.step)
+                        self._jit_for_batch(batch_np)
+                    batch = batch_np
+                    self.timer.start()
+                    self.state, metrics = self.step_fn(self.state, batch)
+                    with jax.profiler.TraceAnnotation("trainer.fetch"):
+                        metrics = {k: float(v) for k, v in metrics.items()}
+                    dt = self.timer.stop(self.step)
+                    self.data_state.step += 1
+                    last_metrics = dict(metrics, step=self.step,
+                                        step_time=dt)
+                    self._log(last_metrics)
+                    if self.metrics_hook:
+                        self.metrics_hook(self.step, last_metrics)
+                    if self.fail_at_step is not None \
+                            and self.step == self.fail_at_step:
+                        raise RuntimeError(
+                            f"injected failure at step {self.step}")
+                    if self.ckpt and self.step % self.ckpt_every == 0:
+                        self.save()
         if self.ckpt:
             self.save(block=True)
             self.ckpt.wait()

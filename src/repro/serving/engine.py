@@ -206,10 +206,12 @@ class ServeEngine:
         Under a mesh every call runs inside the engine's mesh context so
         *tracing* sees the sharding policy (shard_heads /
         shard_slot_cache activate); on cache-hit calls the context entry
-        is a cheap list push."""
+        is a cheap list push.  The program is named ``name``, so its XLA
+        module reads ``jit_<name>`` in a profile."""
         def counted(*args):
             self._traces[name] = self._traces.get(name, 0) + 1
             return fn(*args)
+        counted.__name__ = counted.__qualname__ = name
         jitted = jax.jit(counted)
         if self._ctx is None:
             return jitted
@@ -339,11 +341,12 @@ class ServeEngine:
         slot axis, so a NaN cannot cross slots: the flag identifies
         exactly the poisoned slot(s)."""
         cache = state["cache"]
-        last = logits[:, -1]
-        nxt = jnp.argmax(last, axis=-1).astype(jnp.int32)
-        sampled = jnp.take_along_axis(last, nxt[:, None], axis=-1)[:, 0]
         active = state["active"]
-        bad = active & ~jnp.isfinite(sampled)
+        with jax.named_scope("sample"):
+            last = logits[:, -1]
+            nxt = jnp.argmax(last, axis=-1).astype(jnp.int32)
+            sampled = jnp.take_along_axis(last, nxt[:, None], axis=-1)[:, 0]
+            bad = active & ~jnp.isfinite(sampled)
         # inactive slots keep their cursor (their garbage KV write lands
         # on the same in-bounds position every step, and their recurrent
         # state drifts harmlessly — every cache leaf row is fully
@@ -508,6 +511,13 @@ class ServeEngine:
         request in a list iff it finished immediately (1-token gen).
         ``replica`` pins the slot group (scheduler placement); None
         self-places via :meth:`_pick_replica`."""
+        with jax.profiler.TraceAnnotation(
+                "engine.admit", rid=req.rid, tenant=req.tenant_id,
+                prompt_len=len(req.prompt)) as span:
+            return self._admit(req, replica, span)
+
+    def _admit(self, req: Request, replica: Optional[int],
+               span) -> list[Request]:
         plen = int(len(req.prompt))
         if plen < 1:
             raise AdmissionError("empty prompt")
@@ -522,6 +532,7 @@ class ServeEngine:
                 f"({req.max_new_tokens}) - 1 exceeds the engine's "
                 f"max_len {self.max_len}")
         bucket = self.bucket_for(plen)
+        span.set_metadata(bucket=bucket)
         # host-side guard before the traced last-real-token gather: the
         # jitted prefill cannot validate its traced true_len itself.
         # Stays a bare ValueError — plen <= bucket is guaranteed by
@@ -559,10 +570,11 @@ class ServeEngine:
         tokens = np.zeros((1, bucket), np.int32)
         tokens[0, :plen] = np.asarray(req.prompt, np.int32)
         t0 = self._now()
-        state, tok, bad = self._prefill_fns[bucket](
-            self.params, self.registry.bank, self._state, tokens,
-            int(plen), int(slot), int(tslot), int(req.max_new_tokens))
-        first, poisoned = jax.device_get((tok, bad))   # device sync
+        with jax.profiler.TraceAnnotation("engine.prefill", bucket=bucket):
+            state, tok, bad = self._prefill_fns[bucket](
+                self.params, self.registry.bank, self._state, tokens,
+                int(plen), int(slot), int(tslot), int(req.max_new_tokens))
+            first, poisoned = jax.device_get((tok, bad))   # device sync
         self._state = state
         req.slot = slot
         req.admit_s = t0
@@ -611,6 +623,12 @@ class ServeEngine:
         ``nonfinite`` outcomes."""
         if not self._requests:
             return []
+        with jax.profiler.TraceAnnotation(
+                "engine.step", ordinal=self._step_ordinal,
+                active=len(self._requests)) as span:
+            return self._step(span)
+
+    def _step(self, span) -> list[Request]:
         ordinal = self._step_ordinal
         self._step_ordinal += 1
         if self._faults is not None:
@@ -625,6 +643,7 @@ class ServeEngine:
         tids = {r.tenant_id for r in self._requests.values()}
         merged = (self.registry.merged_for(next(iter(tids)))
                   if len(tids) == 1 else None)
+        span.set_metadata(tier=int(merged is not None), tenants=len(tids))
         t0 = time.perf_counter()
         last_err = None
         for attempt in range(1 + self.step_retries):
@@ -643,7 +662,8 @@ class ServeEngine:
                         self.params, self.registry.bank, self._state)
                 # one fetch returns tokens AND non-finite flags — the
                 # healthy path pays no second device sync for the guard
-                toks, flags = jax.device_get((nxt, bad))
+                with jax.profiler.TraceAnnotation("engine.step.fetch"):
+                    toks, flags = jax.device_get((nxt, bad))
                 break
             except RuntimeError as e:
                 # XLA/Pallas runtime failure (InjectedFault models it)

@@ -205,16 +205,16 @@ class AdapterRegistry:
                 param_specs(self._params, self._mesh, serve=True),
                 self._mesh)
 
-        def _swap_impl(bank, tree, slot):
+        def registry_swap(bank, tree, slot):
             # traced body: runs only on a jit cache miss, so this count
             # is the compile count (see ServeEngine.jit_cache_misses)
             self.stats["swap_traces"] += 1
             return bank.replace_slot(slot, tree)
 
-        self._swap = (jax.jit(_swap_impl) if swap_out is None else
-                      jax.jit(_swap_impl, out_shardings=swap_out))
+        self._swap = (jax.jit(registry_swap) if swap_out is None else
+                      jax.jit(registry_swap, out_shardings=swap_out))
 
-        def _merge_impl(base, tree):
+        def registry_merge(base, tree):
             # same trace-counting discipline as _swap: adapter trees
             # share shapes across tenants, so every promotion after the
             # first is a jit cache hit — the merge ops are charged once
@@ -226,8 +226,8 @@ class AdapterRegistry:
             with mesh_context(MeshContext(self._mesh, seq_shard=False)):
                 return merge_params(base, tree, self._peft)
 
-        self._merge = (jax.jit(_merge_impl) if merge_out is None else
-                       jax.jit(_merge_impl, out_shardings=merge_out))
+        self._merge = (jax.jit(registry_merge) if merge_out is None else
+                       jax.jit(registry_merge, out_shardings=merge_out))
 
     # -- mesh placement (DESIGN.md §14) --------------------------------
 
@@ -315,12 +315,12 @@ class AdapterRegistry:
         reused for every tenant id (no per-tenant recompiles)."""
         base = jax.random.fold_in(self._rng, 0x5eed)
 
-        def _init_impl(tid):
+        def registry_init(tid):
             self.stats["init_traces"] += 1
             return init_adapters(jax.random.fold_in(base, tid),
                                  params, peft)
 
-        fn = jax.jit(_init_impl)
+        fn = jax.jit(registry_init)
         return lambda tid: fn(jnp.int32(tid))
 
     def warm_init(self) -> None:
@@ -504,7 +504,17 @@ class AdapterRegistry:
         Miss: take a free row there, else evict the region's
         least-recently-used *unpinned* tenant; swap the tenant's
         adapters into that row (one jitted functional row update — leaf
-        shapes never change, so nothing retraces)."""
+        shapes never change, so nothing retraces).
+
+        Traced as the span ``registry.acquire`` (stats ``tenant``,
+        ``hit``) with, on a miss, a child ``registry.swap`` (stat
+        ``slot``) from materializing the adapters to the swapped bank
+        being ready."""
+        with jax.profiler.TraceAnnotation(
+                "registry.acquire", tenant=tenant_id) as span:
+            return self._acquire(tenant_id, region, span)
+
+    def _acquire(self, tenant_id: int, region: int, span) -> int:
         self.validate(tenant_id)
         tid, r = int(tenant_id), int(region)
         if tid in self._quarantined:
@@ -512,18 +522,22 @@ class AdapterRegistry:
             # poisoned adapter must never re-enter the batch
             raise QuarantineError(f"tenant {tid} is quarantined")
         slot = self._slots_of.get(tid, {}).get(r)
+        span.set_metadata(hit=int(slot is not None))
         if slot is not None:
             self.stats["hits"] += 1
         else:
             self.stats["misses"] += 1
-            # materialize BEFORE taking a slot: a durable-load failure
-            # (QuarantineError) must leave the slot maps untouched
-            tree = self.adapters_for(tid)
-            slot = self._take_slot(r)
-            first_copy = tid not in self._slots_of
-            self._slots_of.setdefault(tid, {})[r] = slot
-            self._tenant_of[slot] = tid
-            self._swap_in(slot, tree)
+            with jax.profiler.TraceAnnotation("registry.swap") as swap:
+                # materialize BEFORE taking a slot: a durable-load
+                # failure (QuarantineError) must leave the slot maps
+                # untouched
+                tree = self.adapters_for(tid)
+                slot = self._take_slot(r)
+                swap.set_metadata(slot=slot)
+                first_copy = tid not in self._slots_of
+                self._slots_of.setdefault(tid, {})[r] = slot
+                self._tenant_of[slot] = tid
+                self._swap_in(slot, tree)
             if first_copy:
                 self._jlog("onboard", tid)
         self._lru[r][tid] = None
@@ -711,7 +725,8 @@ class AdapterRegistry:
                 self.stats["merges_skipped"] += 1
                 return False
         t0 = time.perf_counter()
-        tree = self._dispatch_merge(tid)
+        with jax.profiler.TraceAnnotation("registry.merge", tenant=tid):
+            tree = self._dispatch_merge(tid)
         self.stats["merge_s"] += time.perf_counter() - t0
         if tree is None:
             # retries exhausted: return the slot, fence the tenant to
