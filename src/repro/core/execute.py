@@ -136,8 +136,14 @@ def dispatch(op: str, backend: str, *args, **kwargs):
     key = f"{op}.{be}"
     if be == "pallas" and not supports(op, *args, **kwargs):
         key = f"{op}.pallas_fallback"
-    _COUNTERS[key] = _COUNTERS.get(key, 0) + 1
+    count(key)
     return impl(*args, **kwargs)
+
+
+def count(key: str) -> None:
+    """Record one trace of ``key`` (an ``op.backend`` pair, or a kernel
+    layout such as ``householder_gemm_batched.pallas_rows``)."""
+    _COUNTERS[key] = _COUNTERS.get(key, 0) + 1
 
 
 def is_bwd_op(op: str) -> bool:

@@ -11,7 +11,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.core.execute import (_interpret, gemm_tiles, lane_ok,
+from repro.core.execute import (_interpret, count, gemm_tiles, lane_ok,
                                 largest_divisor)
 from repro.kernels import ref
 from repro.kernels.delora_gemm import (delora_gemm_pallas,
@@ -31,7 +31,7 @@ from repro.kernels.gemm_bwd import (householder_gemm_batched_bwd_pallas,
                                     reflect_gemm_dw_pallas)
 from repro.kernels.householder_gemm import householder_gemm_pallas
 from repro.kernels.householder_gemm_batched import (
-    householder_gemm_batched_pallas)
+    fold_tiles, householder_gemm_batched_pallas)
 from repro.kernels.hyperadapt_gemm import (hyperadapt_gemm_pallas,
                                            hyperadapt_gemm_batched_pallas)
 from repro.kernels.method_merge import (delora_merge_pallas,
@@ -121,13 +121,18 @@ def householder_gemm_batched(x: jax.Array, w: jax.Array,
                              interpret: bool | None = None) -> jax.Array:
     """Fused tenant-gather + reflect + GEMM. x: (B, S, d); w: (d, f);
     u_bank: (A, n, db); ids: (B,). Falls back to the jnp ref for
-    non-tileable shapes."""
+    non-tileable shapes.  One row per sequence (S == 1, decode) takes
+    the kernel's folded grid, with its own tiles, counted at trace time
+    as ``householder_gemm_batched.pallas_rows``."""
     _, s, d = x.shape
     _, f = w.shape
     _, n, db = u_bank.shape
     bs, bf, bk = gemm_tiles(s, d, f, db)
     if n * db != d or not (bs and bf and bk):
         return ref.ref_householder_gemm_batched(x, w, u_bank, ids)
+    if s == 1:
+        count("householder_gemm_batched.pallas_rows")
+        bf, bk = fold_tiles(d, f, db, bf, bk, w.dtype.itemsize)
     return householder_gemm_batched_pallas(x, w, u_bank, ids, block_s=bs,
                                            block_f=bf, block_k=bk,
                                            interpret=interpret)

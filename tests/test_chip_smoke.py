@@ -35,7 +35,10 @@ def test_serve_phase_smoke(smoke):
     out = smoke.serve_phase("smoke", seed=0)
     assert out["promotions"] >= 1
     assert max(out["logits_rel_l2"]) <= smoke.LOGITS_RTOL
-    assert all(k.endswith(".pallas") for k in out["counters"])
+    assert all(k.endswith((".pallas", ".pallas_rows"))
+               for k in out["counters"])
+    # the bank decode step ran the folded grid of the bank GEMM
+    assert out["counters"]["householder_gemm_batched.pallas_rows"] >= 1
     json.dumps(out, default=str)                # printable as a phase line
 
 

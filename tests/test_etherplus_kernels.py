@@ -140,8 +140,60 @@ def test_householder_gemm_batched_oracle(B, S, shapes, A, seed):
     np.testing.assert_allclose(np.asarray(out), np.asarray(exp), **TOL)
 
 
-def test_householder_gemm_batched_grad_matches_jnp():
-    B, S, d, f, n, A = 3, 16, 128, 128, 4, 5
+# ids per slot: all one tenant, all different, repeats with unreferenced
+# rows between them (bank of 9 rows)
+FOLD_IDS = {"same": lambda b: [4] * b,
+            "distinct": lambda b: [8 - i for i in range(b)],
+            "gaps": lambda b: [(0, 5, 0, 8, 5, 8, 0, 5)[i] for i in range(b)]}
+
+
+@pytest.mark.parametrize("shapes", [(256, 384, 4), (512, 256, 2)],
+                         ids=["db64", "db256"])
+@pytest.mark.parametrize("pattern", sorted(FOLD_IDS))
+@pytest.mark.parametrize("B", [1, 3, 8])
+def test_householder_gemm_batched_folded_oracle(B, pattern, shapes):
+    """S == 1 (decode) takes the folded grid: through ``ops`` at its
+    decode tiles, and at the smallest legal tiles (several F- and
+    K-tiles, so the reflected-row scratch is filled and re-read)."""
+    d, f, n = shapes
+    db = d // n
+    k = jax.random.fold_in(RNG, 1000 * B + d)
+    x = _rand(k, (B, 1, d))
+    w = _rand(jax.random.fold_in(k, 1), (d, f))
+    bank = _rand(jax.random.fold_in(k, 2), (9, n, db))
+    ids = jnp.array(FOLD_IDS[pattern](B), jnp.int32)
+    exp = ref.ref_householder_gemm_batched(x, w, bank, ids)
+    execute.reset_counters()
+    out = ops.householder_gemm_batched(x, w, bank, ids, interpret=True)
+    assert execute.counters() == {"householder_gemm_batched.pallas_rows": 1}
+    small = householder_gemm_batched_pallas(
+        x, w, bank, ids, block_f=128, block_k=max(db, 128), interpret=True)
+    for y in (out, small):
+        assert y.shape == (B, 1, f)
+        np.testing.assert_allclose(np.asarray(y), np.asarray(exp), **TOL)
+
+
+def test_householder_gemm_batched_folded_row_matches_per_sequence():
+    """A row decoded alone (S == 1, folded grid) equals the same row
+    inside an S == 16 call (per-sequence grid): one computation."""
+    B, S, d, f, n = 3, 16, 256, 384, 4
+    x = _rand(RNG, (B, S, d))
+    w = _rand(jax.random.fold_in(RNG, 1), (d, f))
+    bank = _rand(jax.random.fold_in(RNG, 2), (9, n, d // n))
+    ids = jnp.array([5, 0, 5], jnp.int32)
+    execute.reset_counters()
+    whole = ops.householder_gemm_batched(x, w, bank, ids, interpret=True)
+    assert execute.counters() == {}                  # S > 1: not folded
+    one = ops.householder_gemm_batched(x[:, 7:8], w, bank, ids,
+                                       interpret=True)
+    assert execute.counters() == {"householder_gemm_batched.pallas_rows": 1}
+    np.testing.assert_allclose(np.asarray(one[:, 0]),
+                               np.asarray(whole[:, 7]), atol=1e-4,
+                               rtol=1e-5)
+
+
+def _batched_grads(S):
+    B, d, f, n, A = 3, 128, 128, 4, 5
     x = _rand(RNG, (B, S, d))
     w = _rand(jax.random.fold_in(RNG, 1), (d, f))
     bank = _rand(jax.random.fold_in(RNG, 2), (A, n, d // n))
@@ -151,6 +203,7 @@ def test_householder_gemm_batched_grad_matches_jnp():
         return jnp.sum(execute.dispatch("householder_gemm_batched",
                                         backend, x, w, b, ids) ** 2)
 
+    execute.reset_counters()
     g_jnp = jax.grad(lambda b: loss(b, "jnp"))(bank)
     g_pal = jax.grad(lambda b: loss(b, "pallas"))(bank)
     np.testing.assert_allclose(np.asarray(g_pal), np.asarray(g_jnp),
@@ -158,6 +211,19 @@ def test_householder_gemm_batched_grad_matches_jnp():
     # rows no request references must get zero gradient (isolation)
     np.testing.assert_allclose(np.asarray(g_jnp[1]), 0.0, atol=1e-6)
     np.testing.assert_allclose(np.asarray(g_pal[1]), 0.0, atol=1e-6)
+    return execute.counters()
+
+
+def test_householder_gemm_batched_grad_matches_jnp():
+    c = _batched_grads(16)
+    assert "householder_gemm_batched.pallas_rows" not in c
+
+
+def test_householder_gemm_batched_grad_matches_jnp_s1():
+    """S == 1: the folded forward under the per-sequence backward."""
+    c = _batched_grads(1)
+    assert c["householder_gemm_batched.pallas_rows"] == 1
+    assert c["householder_gemm_batched_bwd.pallas"] == 1
 
 
 # ---------------------------------------------------------------------------

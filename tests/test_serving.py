@@ -283,6 +283,37 @@ def test_engine_windowed_attention_and_unscanned_layers():
                     prompt_buckets=(48,), max_new_tokens=8)
 
 
+def test_engine_decode_folds_bank_gemm_rows_and_prefill_does_not():
+    """The bank decode step (one row per slot) runs the bank
+    reflect-GEMM's folded grid; the prefill (a bucket of rows per
+    sequence) keeps the per-sequence grid.  Trace-time counters."""
+    from repro.core import execute
+    from repro.models.backbone import ModelConfig
+    cfg = ModelConfig(name="fold-smoke", n_layers=2, d_model=64, n_heads=2,
+                      n_kv=1, d_ff=128, vocab=128)
+    peft = PEFTConfig(method="ether", n_blocks=4, targets="q_proj|o_proj",
+                      backend="pallas")
+    params = init_model(RNG, cfg)
+    reg = AdapterRegistry(params, peft, 2, n_tenants=4,
+                          rng=jax.random.fold_in(RNG, 3))
+    eng = ServeEngine(cfg, params, reg, peft, slots=2,
+                      prompt_buckets=(16,), max_new_tokens=4)
+    rows = "householder_gemm_batched.pallas_rows"
+    execute.reset_counters()
+    for rid, tenant in enumerate((1, 3)):
+        eng.admit(Request(rid=rid, tenant_id=tenant,
+                          prompt=np.arange(1, 9, dtype=np.int32),
+                          max_new_tokens=4))
+    prefill = execute.counters()
+    assert prefill.get("householder_gemm_batched.pallas", 0) >= 2
+    assert rows not in prefill
+    execute.reset_counters()
+    eng.step()
+    decode = execute.counters()
+    assert decode.get(rows, 0) >= 2                     # q_proj, o_proj
+    assert decode[rows] == decode["householder_gemm_batched.pallas"]
+
+
 def test_engine_backpressure_when_pinned_tenants_exceed_capacity():
     """More decode slots than bank capacity + all-distinct tenants: the
     scheduler must serialize on the registry (requeue + wait) instead

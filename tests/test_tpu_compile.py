@@ -58,6 +58,7 @@ def _bank(d):
     (1, 512, D, FF),        # prefill, up_proj (db = 64)
     (1, 512, FF, D),        # prefill, down_proj (db = 256)
     (8, 1, D, D),           # decode, one token per slot
+    (8, 1, D, FF),          # decode, up_proj: the folded grid's widest F
     (8, 1, FF, D),
 ])
 def test_householder_gemm_batched_compiles(one_chip, rows, seq, d, f):
