@@ -15,19 +15,20 @@ the end of its last step over the number of steps (no partial step);
 ``setup_s`` is process start to window start.
 
 ``correct`` (after the window, the trainer freed, the weights made again
-from the seed): the plain float32 reference (``bench/model.py``) takes
-the same checked steps from the same adapters and batches.  Compared:
-each step's loss (relative gap), the norm of the first step's gradient
-as the optimizer got it (from Adam's first moment after one step:
-mu / (1 - b1)) and the norm of the adapters' change over the checked
-steps, both per leaf (one adapted projection of one layer) and by the
-worst leaf: the gap between the program's norm and the reference's over
-the larger of the reference's leaf norm and its median leaf norm.
-Leaves whose reference gradient is under a thousandth of the median
-leaf's are left out.  With ``Run.control`` the control (the reference
-one precision below the configuration's) is put in the program's place
-and compared instead; the program's numbers, and those of a reference
-step over half the batch, are readings beside it.
+from the seed): the plain float32 reference (``bench/model.py`` over
+the configuration's architecture module) takes the same checked steps
+from the same adapters and batches.  Compared: each step's loss
+(relative gap), the norm of the first step's gradient as the optimizer
+got it (from Adam's first moment after one step: mu / (1 - b1)) and the
+norm of the adapters' change over the checked steps, both per leaf (one
+adapted projection of one layer) and by the worst leaf: the gap between
+the program's norm and the reference's over the larger of the
+reference's leaf norm and its median leaf norm.  Leaves whose reference
+gradient is under a thousandth of the median leaf's are left out.  With
+``Run.control`` the control (the reference one precision below the
+configuration's) is put in the program's place and compared instead;
+the program's numbers, and those of a reference step over half the
+batch, are readings beside it.
 """
 
 from __future__ import annotations
@@ -102,13 +103,14 @@ def run(r: harness.Run) -> harness.Outcome:
     cfg, mix = r.cfg, r.mix
     execute.reset_counters()
     o = mix["optimizer"]
-    mcfg, peft = system.model_config(cfg), system.peft_config(cfg)
+    block = harness.arch(cfg)
+    mcfg, peft = block.model_config(cfg), system.peft_config(cfg)
     opt = adamw(constant(o["lr"]), b1=o["b1"], b2=o["b2"], eps=o["eps"],
                 weight_decay=0.0, clip_norm=o["clip"])
     trainer = Trainer(mcfg, peft, opt, restore="none")
     trainer.state = None                    # the program's own init
     gc.collect()
-    weights = model.make_weights(cfg, r.seed)
+    weights = block.make_weights(cfg, r.seed)
     a0 = model.adapter_fn(cfg, r.seed)(0)
     system.check_layout(cfg, mcfg, peft, weights, a0)
     a0_host = jax.device_get(a0)
@@ -204,7 +206,7 @@ def reference_readings(cfg, mix, seed, stream, a0, quant=None, rows=None):
     and adapters after the checked steps."""
     import jax
     import jax.numpy as jnp
-    weights = model.make_weights(cfg, seed)
+    weights = harness.arch(cfg).make_weights(cfg, seed)
     step = model.train_steps(cfg, mix["optimizer"], quant, rows)
     a = jax.tree_util.tree_map(jnp.asarray, a0)
     mu = jax.tree_util.tree_map(jnp.zeros_like, a)

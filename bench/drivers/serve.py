@@ -27,12 +27,13 @@ After the window, and after the device's peak memory is read and the
 engine is freed, a sample of the completed requests drawn from the seed
 (the longest one and others up to ``check.requests`` and
 ``check.min_tokens`` served tokens) goes through the plain float32
-reference (``bench/model.py``): the widest gap by which a served token's
-reference logit lies below the reference's best is compared with the
-configuration's limit.  With ``Run.control`` the control (the reference
-one precision below the configuration's) is put in the program's place:
-the compared gap is that of the token it puts first at each served
-position, and the program's own gap is a reading beside it.
+reference (``bench/model.py`` over the configuration's architecture
+module): the widest gap by which a served token's reference logit lies
+below the reference's best is compared with the configuration's limit.
+With ``Run.control`` the control (the reference one precision below the
+configuration's) is put in the program's place: the compared gap is
+that of the token it puts first at each served position, and the
+program's own gap is a reading beside it.
 """
 
 from __future__ import annotations
@@ -161,8 +162,9 @@ def run(r: harness.Run) -> harness.Outcome:
     cfg, mix = r.cfg, r.mix
     execute.reset_counters()
     sv = cfg["serving"]
-    mcfg, peft = system.model_config(cfg), system.peft_config(cfg)
-    weights = model.make_weights(cfg, r.seed)
+    block = harness.arch(cfg)
+    mcfg, peft = block.model_config(cfg), system.peft_config(cfg)
+    weights = block.make_weights(cfg, r.seed)
     adapters = model.adapter_fn(cfg, r.seed)
     system.check_layout(cfg, mcfg, peft, weights, adapters(0))
     registry = AdapterRegistry(weights, peft, sv["bank_rows"],

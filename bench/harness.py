@@ -5,11 +5,39 @@ result line.
     python3 bench/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
 Everything a cell needs is found by name from ``BENCHMARK.json``: the
-configuration at its ``file``, the traffic mix at
-``bench/traffic/<traffic>.json``, the driver at
-``bench/drivers/<kind>.py`` (``kind`` is the mix's), and each per-layer
-metric's reader at ``bench/metrics/<metric>.py``.  A later cell adds
-files and entries; it edits none of these.
+configuration at its ``file``, its architecture at
+``bench/archs/<arch>.py`` (the file's ``arch`` key, ``dense`` where it
+has none), the traffic mix at ``bench/traffic/<traffic>.json``, the
+driver at ``bench/drivers/<kind>.py`` (``kind`` is the mix's), and each
+per-layer metric's reader at ``bench/metrics/<metric>.py``.  A later
+cell adds files and entries; it edits none of these.
+
+An architecture module supplies only what depends on its block; the
+drivers, the readers and the shared code (``bench/model.py``,
+``bench/system.py``, ``bench/counts.py``) reach it through
+:func:`arch`.  Each function takes the configuration file's dict:
+
+* ``dims(cfg)``: its sizes;
+* ``projections(cfg)``: a list of ``model.Unit``, one per pattern
+  position of the stack: where its leaves sit in the weight tree, its
+  number of layers, and ``(d_in, d_out)`` of each projection by path
+  (``mixer/q_proj``); adapters, the bank-GEMM counts and the readers
+  are built from it;
+* ``make_weights(cfg, seed)``: the weights in the program's tree, in
+  one jitted call on the device;
+* ``reference_hidden(cfg, weights, adapter, tokens, quant, remat)``:
+  the plain float32 forward to the final hidden states (B, T, d), from
+  the pieces in ``bench/model.py``; the reference programs put the
+  output head on it;
+* ``model_config(cfg)``: the program's ``ModelConfig``, refusing what
+  the program cannot run;
+* ``weight_bytes(cfg)``, ``decode_step(cfg, ctx_lens, tenants, bank)``,
+  ``prefill(cfg, prompt_len, bank)``, ``train_step(cfg, batch, seq)``:
+  the work counts, ``(flops, bytes)`` as ``bench/counts.py`` defines
+  them.
+
+A new architecture is a new module there and configuration files that
+name it; it imports the pieces it shares and writes only its own block.
 
 The last line of standard output is one JSON object: ``correct``,
 ``attempted``, ``failed``, ``metrics`` (the cell's end-to-end metrics
@@ -23,6 +51,7 @@ from __future__ import annotations
 
 import argparse
 import dataclasses
+import functools
 import importlib
 import importlib.util
 import json
@@ -115,14 +144,34 @@ def driver(kind: str):
     return importlib.import_module(f"bench.drivers.{kind}")
 
 
-def reader(metric: str):
-    """The per-layer metric's reader module, ``bench/metrics/<name>.py``."""
-    path = os.path.join(BENCH, "metrics", metric + ".py")
+@functools.lru_cache(maxsize=None)
+def load_module(path: str):
+    """The module in the file at ``path``, loaded once per process."""
+    name = os.path.splitext(os.path.relpath(path, ROOT))[0]
     sp = importlib.util.spec_from_file_location(
-        "bench_metric_" + metric.replace(".", "_"), path)
+        "bench_file_" + name.replace(os.sep, "_").replace(".", "_"), path)
     mod = importlib.util.module_from_spec(sp)
     sp.loader.exec_module(mod)
     return mod
+
+
+def reader(metric: str):
+    """The per-layer metric's reader module, ``bench/metrics/<name>.py``."""
+    return load_module(os.path.join(BENCH, "metrics", metric + ".py"))
+
+
+def arch(cfg: dict):
+    """The configuration's architecture module, ``bench/archs/<arch>.py``
+    (the file's ``arch`` key, ``dense`` where it has none)."""
+    name = cfg.get("arch", "dense")
+    folder = os.path.join(BENCH, "archs")
+    path = os.path.join(folder, name + ".py")
+    if not os.path.isfile(path):
+        have = sorted(f[:-3] for f in os.listdir(folder)
+                      if f.endswith(".py")) if os.path.isdir(folder) else []
+        raise KeyError(f"{cfg.get('name')}: no architecture {name!r} in "
+                       f"bench/archs (have {have})")
+    return load_module(path)
 
 
 def applies(metric: dict, cell: str) -> bool:

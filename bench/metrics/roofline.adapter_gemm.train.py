@@ -9,7 +9,7 @@ forward recomputed under rematerialisation is kernel time but not needed
 work.  Nothing to read where the kernels do not run.
 Layer: adapter kernels.  Moves ``finetune_step_ms``."""
 
-from bench import counts, model
+from bench import counts, harness, model
 from bench import trace as tr
 
 KERNELS = ("householder_gemm_pallas", "reflect_gemm_dx_pallas")
@@ -20,10 +20,10 @@ def read(out):
     if t is None or lay.peak is None:
         return None
     tokens = lay.mix["batch"] * lay.mix["seq"]
-    shapes = model.kernel_shapes(lay.cfg)
-    L = model.dims(lay.cfg)["L"]
-    per_step = sum(counts.seconds(counts.reflect_gemm_train(
-        tokens, *shapes[n]), lay.peak)[0] for n in model.targets(lay.cfg)) * L
+    per_step = sum(u.count * sum(
+        counts.seconds(counts.reflect_gemm_train(tokens, *u.shapes[p]),
+                       lay.peak)[0] for p in model.targets(lay.cfg, u.shapes))
+        for u in harness.arch(lay.cfg).projections(lay.cfg))
     spans = tr.step_spans(t)
     need = spent = 0.0
     for s in lay.steps:

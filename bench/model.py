@@ -1,18 +1,20 @@
-"""The benchmark's own side of a model: weights and tenant adapters made
-from the seed, and the plain reference that decides ``correct``.
+"""The benchmark's own side of a model, shared by every architecture:
+tenant adapters made from the seed, the pieces a plain reference is
+built from, and the reference programs that decide ``correct``.
 
-Nothing here imports the system under test.  The weights are laid out in
-the tree the system's backbone reads (stacked per layer, one ``pos0``
-pattern unit), so the harness hands the same arrays to the system and
-to the reference.
+Nothing here imports the system under test, and nothing here knows a
+block: what a configuration's block is (its sizes, its projections, its
+weights, its forward pass) is its architecture module's,
+``bench/archs/<arch>.py``, found by ``harness.arch``.  The weights are
+laid out in the tree the system's backbone reads, so the harness hands
+the same arrays to the system and to the reference.
 
-The reference is a straightforward float32 forward pass of the block the
-configuration file states: pre-norm RMSNorm, rotary attention, a GELU
-two-matrix or SwiGLU MLP, and ETHER reflections of the inputs of every
+The reference is a straightforward float32 forward pass, under
+``highest`` precision, with ETHER reflections of the inputs of every
 targeted projection (paper Eq. 3: H = I - 2 u u^T / |u|^2, one block per
-n-th of the input width).  It runs layer by layer under a scan, casting
-each layer's bf16 weights to float32 inside, so the whole model never
-exists in float32 at once.
+n-th of the input width).  An architecture runs it layer by layer,
+casting each layer's bf16 weights to float32 inside, so the whole model
+never exists in float32 at once.
 
 ``quant`` gives a control, the same forward computed one precision below
 the configuration's (the mix file names it): ``fp8`` or ``int8``, the
@@ -24,12 +26,23 @@ values, activations scaled per token and weights per output channel;
 from __future__ import annotations
 
 import math
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-MIXER = ("q_proj", "k_proj", "v_proj", "o_proj")
+from bench import harness
+
+
+class Unit(NamedTuple):
+    """One pattern position of a stack: where its layers' leaves sit in
+    the weight tree (``units/pos0``), how many layers it stacks, and
+    ``(d_in, d_out)`` of each of its projections by path under the unit
+    (``mixer/q_proj``)."""
+    where: str
+    count: int
+    shapes: dict
 
 
 def key(seed: int, *tags: int) -> jax.Array:
@@ -41,38 +54,11 @@ def key(seed: int, *tags: int) -> jax.Array:
     return k
 
 
-def dims(cfg: dict) -> dict:
-    """The sizes the reference and the counts read from a config file."""
-    d, h = cfg["hidden_size"], cfg["num_attention_heads"]
-    return dict(
-        L=cfg["num_hidden_layers"], d=d, H=h,
-        Hkv=cfg.get("num_key_value_heads") or h, hd=d // h,
-        ff=cfg["intermediate_size"], V=cfg["vocab_size"],
-        glu=not cfg["hidden_act"].startswith("gelu"),
-        tied=bool(cfg["tie_word_embeddings"]),
-        eps=float(cfg.get("rms_norm_eps", cfg.get("layer_norm_eps"))),
-        theta=float(cfg["rope_theta"]))
-
-
-def kernel_shapes(cfg: dict) -> dict[str, tuple[int, int]]:
-    """(d_in, d_out) of every per-layer projection."""
-    m = dims(cfg)
-    d, hd = m["d"], m["hd"]
-    out = {"q_proj": (d, m["H"] * hd), "k_proj": (d, m["Hkv"] * hd),
-           "v_proj": (d, m["Hkv"] * hd), "o_proj": (m["H"] * hd, d),
-           "up_proj": (d, m["ff"]), "down_proj": (m["ff"], d)}
-    if m["glu"]:
-        out["gate_proj"] = (d, m["ff"])
-    return out
-
-
-def _group(name: str) -> str:
-    return "mixer" if name in MIXER else "mlp"
-
-
-def targets(cfg: dict) -> tuple[str, ...]:
-    return tuple(t for t in cfg["adapter"]["targets"]
-                 if t in kernel_shapes(cfg))
+def targets(cfg: dict, shapes: dict) -> tuple[str, ...]:
+    """The paths of ``shapes`` that the adapter targets, in the order of
+    the configuration's ``targets``."""
+    return tuple(p for t in cfg["adapter"]["targets"] for p in shapes
+                 if p.rsplit("/", 1)[-1] == t)
 
 
 def n_blocks(cfg: dict, d_in: int) -> int:
@@ -83,62 +69,42 @@ def n_blocks(cfg: dict, d_in: int) -> int:
     return n
 
 
-def make_weights(cfg: dict, seed: int):
-    """Random bf16 weights in the system's tree, in one jitted call on
-    the device: lecun-normal projections, N(0, 0.02) embeddings, unit
-    norm scales."""
-    m = dims(cfg)
-    dt = jnp.dtype(cfg["torch_dtype"])
-    shapes = kernel_shapes(cfg)
-
-    def build(k):
-        ks = jax.random.split(k, len(shapes) + 2)
-        layer = {"norm1": {"scale": jnp.ones((m["L"], m["d"]), dt)},
-                 "norm2": {"scale": jnp.ones((m["L"], m["d"]), dt)},
-                 "mixer": {}, "mlp": {}}
-        for kk, (name, (di, do)) in zip(ks[2:], sorted(shapes.items())):
-            w = jax.random.normal(kk, (m["L"], di, do), dt)
-            layer[_group(name)][name] = {
-                "kernel": w * jnp.asarray(1.0 / math.sqrt(di), dt)}
-        tree = {"embed": {"table": jax.random.normal(
-                    ks[0], (m["V"], m["d"]), dt) * jnp.asarray(0.02, dt)},
-                "final_norm": {"scale": jnp.ones((m["d"],), dt)},
-                "units": {"pos0": layer}}
-        if not m["tied"]:
-            tree["lm_head"] = {"kernel": jax.random.normal(
-                ks[1], (m["d"], m["V"]), dt)
-                * jnp.asarray(1.0 / math.sqrt(m["d"]), dt)}
-        return tree
-
-    return jax.jit(build)(key(seed, 1))
+def _put(tree: dict, path: str, leaf) -> None:
+    *parents, last = path.split("/")
+    for p in parents:
+        tree = tree.setdefault(p, {})
+    tree[last] = leaf
 
 
 def adapter_fn(cfg: dict, seed: int):
     """``tid -> adapter tree`` for the tenant universe: ETHER hyperplanes
     u ~ N(0, 1) per block (the paper's random start, distance 2 from the
-    identity), float32, one jitted program for every tenant."""
-    m = dims(cfg)
-    shapes = kernel_shapes(cfg)
+    identity), float32, one jitted program for every tenant.  The i-th
+    targeted projection, counted over the units in order, takes the
+    tenant's key folded with i."""
+    units = harness.arch(cfg).projections(cfg)
     dt = jnp.dtype(cfg["adapter"]["dtype"])
-    names = targets(cfg)
     base = key(seed, 2)
 
     @jax.jit
     def build(tid):
         k = jax.random.fold_in(base, tid)
-        out = {"mixer": {}, "mlp": {}}
-        for i, name in enumerate(names):
-            di = shapes[name][0]
-            n = n_blocks(cfg, di)
-            out[_group(name)][name] = {"u": jax.random.normal(
-                jax.random.fold_in(k, i), (m["L"], n, di // n), dt)}
-        return {"units": {"pos0": {g: v for g, v in out.items() if v}}}
+        out: dict = {}
+        i = 0
+        for u in units:
+            for p in targets(cfg, u.shapes):
+                di = u.shapes[p][0]
+                n = n_blocks(cfg, di)
+                _put(out, f"{u.where}/{p}/u", jax.random.normal(
+                    jax.random.fold_in(k, i), (u.count, n, di // n), dt))
+                i += 1
+        return out
 
     return lambda tid: build(jnp.uint32(tid))
 
 
 # ---------------------------------------------------------------------------
-# Reference forward
+# Pieces of a reference forward
 # ---------------------------------------------------------------------------
 
 def _rmsnorm(x, scale, eps):
@@ -197,66 +163,68 @@ def _matmul(x, w, quant):
     return _q8(x, -1) @ _q8(w, 0)
 
 
-def _head(cfg, weights):
-    f32 = jnp.float32
-    if dims(cfg)["tied"]:
-        return weights["embed"]["table"].astype(f32).T
-    return weights["lm_head"]["kernel"].astype(f32)
+def project(lw, la, path: str, x, quant):
+    """x through one layer's projection at ``path`` (``mixer/q_proj``),
+    its input reflected first where the layer's adapters hold it."""
+    group, name = path.split("/")
+    w = lw[group][name]["kernel"].astype(jnp.float32)
+    if name in la.get(group, {}):
+        x = _reflect(x, la[group][name]["u"].astype(jnp.float32))
+    return _matmul(x, w, quant)
 
 
-def reference_hidden(cfg: dict, weights, adapter, tokens, quant=None,
-                     remat=False):
-    """float32 final hidden states (B, T, d) of token rows (B, T), causal:
-    a row depends only on the tokens up to it, so padding at the end is
-    free.  ``remat`` recomputes each layer in the backward pass."""
-    m = dims(cfg)
-    f32 = jnp.float32
-    B, T = tokens.shape
-    pos = jnp.arange(T, dtype=f32)
-    mask = jnp.tril(jnp.ones((T, T), bool))
-    rep = m["H"] // m["Hkv"]
+def attention(lw, la, h, pos, mask, heads: int, kv_heads: int, theta,
+              quant):
+    """Causal grouped-query self-attention of the program's ``mixer``
+    tree (q, k, v and o projections, no biases): h (B, T, d) to the
+    o projection's output.  ``theta`` is the rotary base over whole
+    heads, or None for no position embedding; ``mask`` (T, T) the
+    causal mask."""
+    B, T, _ = h.shape
+    q = project(lw, la, "mixer/q_proj", h, quant).reshape(B, T, heads, -1)
+    k = project(lw, la, "mixer/k_proj", h, quant).reshape(B, T, kv_heads, -1)
+    v = project(lw, la, "mixer/v_proj", h, quant).reshape(B, T, kv_heads, -1)
+    if theta is not None:
+        q = _rope(q, pos, theta)
+        k = _rope(k, pos, theta)
+    rep = heads // kv_heads
+    k, v = jnp.repeat(k, rep, axis=2), jnp.repeat(v, rep, axis=2)
+    s = jnp.einsum("bthd,bshd->bhts", q, k) / math.sqrt(q.shape[-1])
+    p = jax.nn.softmax(jnp.where(mask, s, -jnp.inf), axis=-1)
+    a = jnp.einsum("bhts,bshd->bthd", p, v).reshape(B, T, -1)
+    return project(lw, la, "mixer/o_proj", a, quant)
 
-    def proj(lw, la, name, x):
-        w = lw[_group(name)][name]["kernel"].astype(f32)
-        if name in la.get(_group(name), {}):
-            x = _reflect(x, la[_group(name)][name]["u"].astype(f32))
-        return _matmul(x, w, quant)
 
-    def layer(x, xs):
-        lw, la = xs
-        h = _rmsnorm(x, lw["norm1"]["scale"].astype(f32), m["eps"])
-        q = proj(lw, la, "q_proj", h).reshape(B, T, m["H"], m["hd"])
-        k = proj(lw, la, "k_proj", h).reshape(B, T, m["Hkv"], m["hd"])
-        v = proj(lw, la, "v_proj", h).reshape(B, T, m["Hkv"], m["hd"])
-        q = _rope(q, pos, m["theta"])
-        k = _rope(k, pos, m["theta"])
-        k, v = jnp.repeat(k, rep, axis=2), jnp.repeat(v, rep, axis=2)
-        s = jnp.einsum("bthd,bshd->bhts", q, k) / math.sqrt(m["hd"])
-        p = jax.nn.softmax(jnp.where(mask, s, -jnp.inf), axis=-1)
-        a = jnp.einsum("bhts,bshd->bthd", p, v).reshape(B, T, -1)
-        x = x + proj(lw, la, "o_proj", a)
-        h = _rmsnorm(x, lw["norm2"]["scale"].astype(f32), m["eps"])
-        if m["glu"]:
-            g = proj(lw, la, "gate_proj", h)
-            z = g * jax.nn.sigmoid(g) * proj(lw, la, "up_proj", h)
-        else:
-            z = proj(lw, la, "up_proj", h)
-            z = 0.5 * z * (1.0 + jnp.tanh(
-                math.sqrt(2.0 / math.pi) * (z + 0.044715 * z ** 3)))
-        return x + proj(lw, la, "down_proj", z), None
+def mlp(lw, la, h, glu: bool, quant):
+    """The program's ``mlp`` tree: SwiGLU (gate, up, down) where ``glu``,
+    else a two-matrix MLP with the tanh GELU."""
+    if glu:
+        g = project(lw, la, "mlp/gate_proj", h, quant)
+        z = g * jax.nn.sigmoid(g) * project(lw, la, "mlp/up_proj", h, quant)
+    else:
+        z = project(lw, la, "mlp/up_proj", h, quant)
+        z = 0.5 * z * (1.0 + jnp.tanh(
+            math.sqrt(2.0 / math.pi) * (z + 0.044715 * z ** 3)))
+    return project(lw, la, "mlp/down_proj", z, quant)
 
-    if remat:
-        layer = jax.checkpoint(layer)
-    x = weights["embed"]["table"][tokens].astype(f32)
-    x, _ = jax.lax.scan(layer, x, (weights["units"]["pos0"],
-                                   adapter["units"]["pos0"]))
-    return _rmsnorm(x, weights["final_norm"]["scale"].astype(f32), m["eps"])
 
+def _head(weights):
+    """The output head's matrix (d, V): the untied head where the tree
+    has one, else the embedding table."""
+    if "lm_head" in weights:
+        return weights["lm_head"]["kernel"].astype(jnp.float32)
+    return weights["embed"]["table"].astype(jnp.float32).T
+
+
+# ---------------------------------------------------------------------------
+# Reference programs
+# ---------------------------------------------------------------------------
 
 def reference_logits(cfg: dict, weights, adapter, tokens, quant=None):
     """float32 logits (T, V) of one token sequence (T,)."""
-    h = reference_hidden(cfg, weights, adapter, tokens[None], quant)[0]
-    return _matmul(h, _head(cfg, weights), quant)
+    h = harness.arch(cfg).reference_hidden(cfg, weights, adapter,
+                                           tokens[None], quant)[0]
+    return _matmul(h, _head(weights), quant)
 
 
 def reference_loss(cfg: dict, weights, adapter, tokens, labels, quant=None,
@@ -266,8 +234,9 @@ def reference_loss(cfg: dict, weights, adapter, tokens, labels, quant=None,
     only the first that many rows (a fault: part of the batch left out)."""
     if rows is not None:
         tokens, labels = tokens[:rows], labels[:rows]
-    h = reference_hidden(cfg, weights, adapter, tokens, quant, remat=True)
-    head = _head(cfg, weights)
+    h = harness.arch(cfg).reference_hidden(cfg, weights, adapter, tokens,
+                                           quant, remat=True)
+    head = _head(weights)
 
     @jax.checkpoint
     def row(args):

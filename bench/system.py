@@ -1,45 +1,27 @@
-"""The bridge from a configuration file to the system under test.
+"""The bridge from a configuration file to the system under test, for
+what every architecture shares: the adapter's ``PEFTConfig``, and the
+check that the benchmark's trees are the ones the program builds.
 
-This is the one module of the benchmark that turns a file's keys into
-the program's own objects (``ModelConfig``, ``PEFTConfig``).  It refuses
-a file that states something the program cannot run, so a configuration
-is never reported under a block it did not get.
+The architecture's half, the ``ModelConfig`` of its block with the
+refusals of what the program cannot run, is its module's
+``model_config`` (``bench/archs/<arch>.py``), so a configuration is
+never reported under a block it did not get.
 """
 
 from __future__ import annotations
 
 import jax
 
-from bench import model
-
-# what the program's dense block hard-codes (models/layers.py, backbone.py)
-PROGRAM_NORM_EPS = 1e-6
-
-
-def model_config(cfg: dict):
-    from repro.models import ModelConfig
-    m = model.dims(cfg)
-    if m["eps"] != PROGRAM_NORM_EPS:
-        raise ValueError(f"{cfg['name']}: norm eps {m['eps']} != the "
-                         f"program's {PROGRAM_NORM_EPS}")
-    if cfg.get("partial_rotary_factor", 1.0) != 1.0:
-        raise ValueError(f"{cfg['name']}: the program rotates whole heads")
-    prog = cfg["program"]
-    return ModelConfig(
-        name=cfg["name"], n_layers=m["L"], d_model=m["d"], n_heads=m["H"],
-        n_kv=m["Hkv"], d_ff=m["ff"], vocab=m["V"],
-        mlp_type="swiglu" if m["glu"] else "gelu",
-        act="silu" if m["glu"] else "gelu", rope_theta=m["theta"],
-        tie_embeddings=m["tied"], param_dtype=cfg["torch_dtype"],
-        compute_dtype=cfg["torch_dtype"], remat=prog["remat"],
-        q_chunk=prog["q_chunk"], loss_chunk=prog["loss_chunk"])
+from bench import harness
 
 
 def peft_config(cfg: dict):
     from repro.core.transforms import PEFTConfig
     a = cfg["adapter"]
+    have = {p.rsplit("/", 1)[-1]
+            for u in harness.arch(cfg).projections(cfg) for p in u.shapes}
     return PEFTConfig(method=a["method"], n_blocks=a["n_blocks"],
-                      targets="|".join(model.targets(cfg)),
+                      targets="|".join(t for t in a["targets"] if t in have),
                       backend=a["backend"], adapter_dtype=a["dtype"])
 
 

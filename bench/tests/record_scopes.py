@@ -3,16 +3,16 @@ program's own spans and scopes on a TPU, read by ``test_bench_scopes.py``.
 
     python3 bench/tests/record_scopes.py <out.xplane.pb>
 
-A two-layer ETHER model at d 256 (``data/scopes.json``, weights and
-adapters from ``bench/model.py``) admits one request into a
-``ServeEngine`` (its tenant not yet in the bank, so the registry swaps
-it in) and takes three bank decode steps, then a ``Trainer`` takes two
-``fit`` steps under ``remat=full``.  Everything compiles first; the
-profiler then records the six calls, 20 ms apart, inside the
-benchmark's own spans as the drivers open them (``bench.window``,
-``bench.admit``, ``bench.step`` with ``n``), which align the device's
-clock.  The file kept is the trace cut to what ``bench/trace.py`` reads
-(:func:`trim`).  Exits 2 without a TPU.
+A two-layer ETHER model at d 256 (``data/scopes.json``, weights from
+its architecture module, adapters from ``bench/model.py``) admits one
+request into a ``ServeEngine`` (its tenant not yet in the bank, so the
+registry swaps it in) and takes three bank decode steps, then a
+``Trainer`` takes two ``fit`` steps under ``remat=full``.  Everything
+compiles first; the profiler then records the six calls, 20 ms apart,
+inside the benchmark's own spans as the drivers open them
+(``bench.window``, ``bench.admit``, ``bench.step`` with ``n``), which
+align the device's clock.  The file kept is the trace cut to what
+``bench/trace.py`` reads (:func:`trim`).  Exits 2 without a TPU.
 """
 
 import os
@@ -138,8 +138,9 @@ def record(out_path: str) -> int:
     cfg = harness.load_json(os.path.join(ROOT, "bench", "tests", "data",
                                          "scopes.json"))
     sv = cfg["serving"]
-    mcfg, peft = system.model_config(cfg), system.peft_config(cfg)
-    weights = model.make_weights(cfg, SEED)
+    block = harness.arch(cfg)
+    mcfg, peft = block.model_config(cfg), system.peft_config(cfg)
+    weights = block.make_weights(cfg, SEED)
     registry = AdapterRegistry(weights, peft, sv["bank_rows"],
                                n_tenants=sv["tenants"],
                                init_fn=model.adapter_fn(cfg, SEED))

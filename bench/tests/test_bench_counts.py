@@ -1,13 +1,14 @@
 """The needed-work counts against hand numbers: the benchmark's Phi-1.5
 configuration, and MiniCPM-2B's published widths (SwiGLU, 36 heads,
-d 2304, vocabulary 122753) as a second shape the counts must hold for."""
+d 2304, vocabulary 122753) as a second shape the counts must hold for.
+Both are dense blocks: their counts are their architecture module's."""
 
 import json
 import os
 
 import pytest
 
-from bench import counts, model
+from bench import counts, harness, model
 
 CONFIGS = os.path.join(os.path.dirname(os.path.dirname(__file__)), "configs")
 
@@ -39,20 +40,21 @@ def test_weight_bytes(phi, cpm):
     # Phi-1.5: 24 x (4 * 2048^2 + 2 * 2048 * 8192 + 2 * 2048) + the
     # 51200 x 2048 output head's matrix + the final norm, 2 bytes each
     layer = 4 * 2048 ** 2 + 2 * 2048 * 8192 + 2 * 2048
-    assert counts.weight_bytes(phi) == 2 * (24 * layer + 51200 * 2048 + 2048)
-    assert counts.weight_bytes(phi) == 2625835008
+    dense = harness.arch(phi)
+    assert dense.weight_bytes(phi) == 2 * (24 * layer + 51200 * 2048 + 2048)
+    assert dense.weight_bytes(phi) == 2625835008
     # MiniCPM-2B: SwiGLU has three 2304 x 5760 matrices
     layer = 4 * 2304 ** 2 + 3 * 2304 * 5760 + 2 * 2304
-    assert counts.weight_bytes(cpm) == 2 * (40 * layer + 122753 * 2304
-                                            + 2304)
+    assert dense.weight_bytes(cpm) == 2 * (40 * layer + 122753 * 2304
+                                           + 2304)
     # tied or not, the output head's matrix is read once
-    assert counts.weight_bytes(dict(phi, tie_word_embeddings=False)) == \
-        counts.weight_bytes(phi)
+    assert dense.weight_bytes(dict(phi, tie_word_embeddings=False)) == \
+        dense.weight_bytes(phi)
 
 
 def test_decode_step_phi(phi):
     # 16 sequences with 600 live positions each, 12 distinct tenants
-    flops, nbytes = counts.decode_step(phi, [600] * 16, 12)
+    flops, nbytes = harness.arch(phi).decode_step(phi, [600] * 16, 12)
     n_layer = 4 * 2048 ** 2 + 2 * 2048 * 8192
     aw = 5 * 2048 + 8192                   # q, k, v, o, up inputs; down
     want_f = (16 * (2 * 24 * n_layer + 2 * 2048 * 51200)
@@ -61,13 +63,13 @@ def test_decode_step_phi(phi):
     want_b = 2625835008 + kv + 12 * 24 * aw * 4
     assert (flops, nbytes) == (want_f, want_b)
     # the merged tier gathers no bank rows and reflects nothing
-    f2, b2 = counts.decode_step(phi, [600] * 16, 12, bank=False)
+    f2, b2 = harness.arch(phi).decode_step(phi, [600] * 16, 12, bank=False)
     assert want_f - f2 == 16 * 24 * 4 * aw and want_b - b2 == 12 * 24 * aw * 4
 
 
 def test_decode_step_minicpm_is_byte_bound(cpm):
     peak = {"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9}
-    work = counts.decode_step(cpm, [700] * 8, 8)
+    work = harness.arch(cpm).decode_step(cpm, [700] * 8, 8)
     t, bound = counts.seconds(work, peak)
     assert bound == "bytes"
     # 5.45 GB of weights, 8 x 700 live positions (and 8 written) x 40
@@ -88,23 +90,26 @@ def test_bank_gemms_phi(phi):
 
 
 def test_prefill_and_train(phi):
-    f, b = counts.prefill(phi, 512)
+    f, b = harness.arch(phi).prefill(phi, 512)
     n_layer = 4 * 2048 ** 2 + 2 * 2048 * 8192
     assert f == (2 * 24 * n_layer * 512 + 2 * 2048 * 51200
                  + 2 * 24 * 32 * 64 * 512 ** 2 + 4 * 24 * (5 * 2048 + 8192)
                  * 512)
-    tf, tb = counts.train_step(phi, 16, 512)
+    tf, tb = harness.arch(phi).train_step(phi, 16, 512)
     n = 24 * n_layer + 2048 * 51200
     attn = 2 * 24 * 32 * 64 * 512 * 512 * 16
     assert tf == 4 * n * 8192 + 3 * attn + 12 * 24 * (5 * 2048 + 8192) * 8192
-    assert tb == 2 * counts.weight_bytes(phi)
+    assert tb == 2 * harness.arch(phi).weight_bytes(phi)
     # about 46 TFLOP per step at batch 16 x 512
     assert 40e12 < tf < 52e12
 
 
 def test_kernel_shapes_and_blocks(phi, cpm):
-    assert model.kernel_shapes(cpm)["down_proj"] == (5760, 2304)
-    assert model.targets(cpm) == ("q_proj", "k_proj", "v_proj", "o_proj",
-                                  "gate_proj", "up_proj", "down_proj")
+    (unit,) = harness.arch(cpm).projections(cpm)
+    assert unit.where == "units/pos0" and unit.count == 40
+    assert unit.shapes["mlp/down_proj"] == (5760, 2304)
+    assert model.targets(cpm, unit.shapes) == (
+        "mixer/q_proj", "mixer/k_proj", "mixer/v_proj", "mixer/o_proj",
+        "mlp/gate_proj", "mlp/up_proj", "mlp/down_proj")
     assert model.n_blocks(cpm, 5760) == 32 and 5760 // 32 == 180
     assert model.n_blocks(phi, 8192) == 32

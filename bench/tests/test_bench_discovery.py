@@ -1,16 +1,22 @@
 """Every cell's files are found by name, and the harness refuses to run
 without a TPU."""
 
+import glob
 import os
+import re
 import shutil
 import subprocess
 import sys
 
 import pytest
 
-from bench import harness
+from bench import harness, model
 
 ROOT = harness.ROOT
+# what an architecture module supplies (bench/harness.py)
+INTERFACE = ("dims", "projections", "make_weights", "reference_hidden",
+             "model_config", "weight_bytes", "decode_step", "prefill",
+             "train_step")
 
 
 def test_benchmark_json_files_found_by_name():
@@ -45,6 +51,48 @@ def test_reader_found_by_name(tmp_path, monkeypatch):
         "def read(out):\n    return 42.0\n")
     monkeypatch.setattr(harness, "BENCH", str(tmp_path))
     assert harness.reader("x.y").read(None) == 42.0
+
+
+def _config_files():
+    spec = harness.spec()
+    files = [os.path.join(ROOT, c["file"]) for c in spec["configs"]]
+    files += [os.path.join(harness.BENCH, "tests", "data", n + ".json")
+              for n in ("tiny", "scopes")]
+    return files
+
+
+@pytest.mark.parametrize("path", _config_files(),
+                         ids=lambda p: os.path.basename(p))
+def test_every_configuration_has_its_architecture(path):
+    cfg = harness.load_json(path)
+    block = harness.arch(cfg)
+    assert all(callable(getattr(block, f, None)) for f in INTERFACE)
+    units = block.projections(cfg)
+    assert units and all(isinstance(u, model.Unit) for u in units)
+    assert sum(u.count for u in units) == cfg["num_hidden_layers"]
+    assert all(model.targets(cfg, u.shapes) for u in units)
+
+
+def test_unknown_architecture_names_what_exists():
+    with pytest.raises(KeyError, match=r"no architecture 'no-such'.*dense"):
+        harness.arch({"name": "x", "arch": "no-such"})
+
+
+def test_only_the_lookup_names_an_architecture():
+    """The drivers, the readers, the shared code and the trace recorder
+    reach the block only through ``harness.arch``."""
+    bench = harness.BENCH
+    files = (glob.glob(os.path.join(bench, "*.py"))
+             + glob.glob(os.path.join(bench, "drivers", "*.py"))
+             + glob.glob(os.path.join(bench, "metrics", "*.py"))
+             + [os.path.join(bench, "tests", "record_scopes.py")])
+    named = re.compile(r"archs(\.|/|\s+import\s+)dense"
+                       r"|[\"']dense(\.py)?[\"']")
+    for path in files:
+        if os.path.basename(path) == "harness.py":
+            continue
+        with open(path) as f:
+            assert not named.search(f.read()), path
 
 
 def test_unknown_workload_and_device_kind():
